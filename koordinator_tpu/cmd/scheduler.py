@@ -22,6 +22,7 @@ from koordinator_tpu.cmd.runtime import (
     default_identity,
     parse_feature_gates,
 )
+from koordinator_tpu.compilecache import enable_persistent_cache
 from koordinator_tpu.features import FeatureGate, new_default_gate
 from koordinator_tpu.scheduler.frameworkext import (
     SchedulerService,
@@ -136,6 +137,7 @@ def build(argv: Optional[Sequence[str]] = None,
 
 def main(argv: Optional[Sequence[str]] = None,
          service: Optional[SchedulerService] = None) -> int:
+    enable_persistent_cache()
     proc = build(argv, service)
     stop = StopHandle().install_signal_handlers()
     proc.run(stop.stopped)

@@ -11,14 +11,19 @@ layer over JAX's persistent compilation cache keyed by (contract hash,
 mesh axes, jax version, backend). `counters` exposes the JAX
 compilation-cache telemetry the warm-start pins assert on.
 
-STRICTLY OPT-IN: nothing here activates by default. XLA:CPU AOT
-artifacts deserialized on a different machine can segfault (the CI
-hosts live-migrate — see tests/conftest.py), so a cache directory is
-only ever safe same-host, and every consumer (service ctor handle,
-BENCH_COMPILE_CACHE, the warm-cache smoke) passes one explicitly.
+Nothing here activates on import. The entry points (chip_smoke.py,
+bench.py, cmd/scheduler.main) call `enable_persistent_cache`, which
+keeps the cache where JAX_COMPILATION_CACHE_DIR says or else in the
+fixed `<repo>/.jax_cache`; the test suite never does (XLA:CPU artifacts
+deserialized on a different machine can segfault — see
+tests/conftest.py).
 """
 
-from koordinator_tpu.compilecache.cache import CompileCache  # noqa: F401
+from koordinator_tpu.compilecache.cache import (  # noqa: F401
+    CompileCache,
+    enable_persistent_cache,
+    persistent_cache_dir,
+)
 from koordinator_tpu.compilecache.counters import (  # noqa: F401
     CompileWatcher,
 )

@@ -21,10 +21,16 @@ dropped manifest entry merely costs a re-lower (the persistent cache
 then usually still hits on the unchanged HLO); a WRONG manifest entry
 would claim warmth the contracts no longer back.
 
-STRICTLY OPT-IN, SAME-HOST ONLY: activate() flips the process-global
-jax_compilation_cache_dir. XLA:CPU artifacts deserialized on a
-different machine can segfault (live-migrating CI hosts — see
-tests/conftest.py), so never ship a cache dir across machines.
+Where the cache lives is decided in ONE place, `persistent_cache_dir`:
+JAX_COMPILATION_CACHE_DIR when the environment sets it, else the fixed
+`<repo>/.jax_cache/` (gitignored). The directory is part of what a
+cached entry is found under, so it is never built from a temporary
+name, a pid or the time. `enable_persistent_cache` points the process
+at it (the entry points call it), and `CompileCache.activate` goes
+through it; nothing takes a directory of its own. XLA:CPU
+artifacts deserialized on a different machine can segfault
+(live-migrating CI hosts — see tests/conftest.py), which is why the
+test suite never enables any of this.
 """
 
 from __future__ import annotations
@@ -41,6 +47,35 @@ log = logging.getLogger(__name__)
 
 MANIFEST_NAME = "manifest.json"
 MANIFEST_VERSION = 1
+
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def persistent_cache_dir() -> str:
+    """The persistent compilation cache directory for this process:
+    JAX_COMPILATION_CACHE_DIR when set, else `<repo>/.jax_cache`."""
+    return os.environ.get(CACHE_DIR_ENV) or REPO_CACHE_DIR
+
+
+def enable_persistent_cache() -> str:
+    """Point JAX's persistent compilation cache at
+    `persistent_cache_dir()` and return the path. Called by the entry
+    points (chip_smoke.py, bench.py, cmd/scheduler.main) before their
+    first compile."""
+    import jax
+
+    path = persistent_cache_dir()
+    os.makedirs(path, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", path)
+    # JAX latches the persistent cache at the FIRST compile of the
+    # process: if anything compiled before this call (even a bare jnp
+    # op building a snapshot), the dir change above is silently ignored
+    # forever. Reset so the next compile re-initializes against it.
+    _reset_jax_persistent_cache()
+    return path
 
 
 def _reset_jax_persistent_cache() -> None:
@@ -60,8 +95,8 @@ def _reset_jax_persistent_cache() -> None:
 class CompileCache:
     """An opt-in, same-host compile cache handle.
 
-    `activate()` points JAX's persistent compilation cache at `path`
-    (clamping the min-compile-time/min-entry-size thresholds so even
+    `activate()` points JAX's persistent compilation cache at
+    `persistent_cache_dir()`, kept as `path` (clamping the min-compile-time/min-entry-size thresholds so even
     small CPU test programs persist) and loads the manifest. `ensure()`
     runs an AOT build (lower+compile) exactly once per cache key —
     in-memory memo first, then the persistent cache absorbs the XLA
@@ -69,9 +104,8 @@ class CompileCache:
     scheduler metrics when a catalog is attached.
     """
 
-    def __init__(self, path: str,
-                 fingerprint: Optional[str] = None) -> None:
-        self.path = path
+    def __init__(self, fingerprint: Optional[str] = None) -> None:
+        self.path = persistent_cache_dir()
         self.fingerprint = (fingerprint if fingerprint is not None
                             else keys.contract_fingerprint())
         self.active = False
@@ -170,26 +204,19 @@ class CompileCache:
     # --- lifecycle --------------------------------------------------------
 
     def activate(self) -> "CompileCache":
-        """Point the process at this cache dir and load the manifest.
-        Idempotent. Opt-in by construction: only an explicit activate()
-        ever touches the process-global persistent-cache config."""
+        """Point the process at the persistent cache
+        (`enable_persistent_cache`) and load the manifest there.
+        Idempotent."""
         if self.active:
             return self
         import jax
 
-        os.makedirs(self.path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", self.path)
+        self.path = enable_persistent_cache()
         # persist EVERYTHING: the scheduler's small CPU-test programs
         # compile in well under the default 1s threshold, and a warmer
         # that silently skips them pins nothing
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # JAX latches the persistent cache at the FIRST compile of the
-        # process: if anything compiled before activate() (even a bare
-        # jnp op building a snapshot), the dir change above is silently
-        # ignored forever. Reset so the next compile re-initializes
-        # against this dir.
-        _reset_jax_persistent_cache()
         counters.install()
         self._load_manifest()
         self.active = True

@@ -31,6 +31,9 @@ _lock = threading.Lock()
 _counts: collections.Counter = collections.Counter()
 _durations: Dict[str, float] = collections.defaultdict(float)
 _installed = False
+# per-thread compile seconds: JAX reports a compile on the thread that
+# asked for it, so a cycle can tell its own compiles from another's
+_thread = threading.local()
 guard_module(__name__, _counts="_lock", _durations="_lock",
              _installed="_lock")
 
@@ -44,6 +47,8 @@ def _on_duration(event: str, duration: float, **_kw) -> None:
     with _lock:
         _counts[event] += 1
         _durations[event] += float(duration)
+    if event == DURATION_BACKEND_COMPILE:
+        _thread.compile_seconds = thread_compile_seconds() + duration
 
 
 def install() -> None:
@@ -65,6 +70,13 @@ def snapshot() -> tuple:
     """(counts, duration sums) copies of the global tallies."""
     with _lock:
         return dict(_counts), dict(_durations)
+
+
+def thread_compile_seconds() -> float:
+    """XLA compile-or-retrieve seconds spent on the CALLING thread so
+    far. Only compiles after `install()` count, so take a reading
+    before the region of interest and subtract."""
+    return getattr(_thread, "compile_seconds", 0.0)
 
 
 class CompileWatcher:

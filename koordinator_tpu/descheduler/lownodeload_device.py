@@ -73,7 +73,8 @@ def _plan_prelude(usage, capacity, fresh, source_mask,
     node_fit eligibility, and the global eviction order. Traced inside
     a jit, never called eagerly."""
     eps = 1e-9
-    sel = lambda x: x @ rdims_onehot.T                    # [.., R]->[.., Rd]
+    sel = lambda x: jnp.matmul(                           # [.., R]->[.., Rd]
+        x, rdims_onehot.T, precision=jax.lax.Precision.HIGHEST)
     pct = 100.0 * sel(usage) / jnp.maximum(sel(capacity), eps)  # [N, Rd]
     if use_deviation:
         nf = jnp.maximum(fresh.sum(), 1)

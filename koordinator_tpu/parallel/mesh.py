@@ -199,10 +199,17 @@ def shard_snapshot(snap: ClusterSnapshot, mesh: Mesh) -> ClusterSnapshot:
     The node count must be divisible by the mesh's node-axis size —
     run the snapshot through `pad_nodes_to_mesh` first when it isn't
     (SnapshotBuilder's max_nodes is the padded size on the typed path).
+
+    Zero-size leaves (the [N, A, 0] aux-device columns of a cluster
+    with no aux devices) replicate: that is the layout a jitted commit
+    returns them in, and publishing any other would make the second
+    cycle recompile the whole sharded program.
     """
+    replicated = NamedSharding(mesh, P())
     shardings = snapshot_sharding(mesh)
     return jax.tree_util.tree_map(
-        lambda x, s: jax.device_put(x, s), snap, shardings)
+        lambda x, s: jax.device_put(x, s if x.size else replicated),
+        snap, shardings)
 
 
 def shard_batch(pods: PodBatch, mesh: Mesh) -> PodBatch:

@@ -31,7 +31,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from koordinator_tpu import obs
@@ -61,18 +60,18 @@ def stage1_mask_sharded(mesh: Mesh, snap: ClusterSnapshot, pods: PodBatch,
     state and is recomputed identically on every shard), and
     bit-identical to the global mask.
 
-    `check_rep=False` because shard_map cannot prove the replicated
+    `check_vma=False` because shard_map cannot prove the replicated
     quota term is shard-invariant; the conformance test does."""
     snap_spec = jax.tree_util.tree_map(lambda s: s.spec,
                                        snapshot_sharding(mesh))
     pods_spec = jax.tree_util.tree_map(lambda _: P(), pods)
     mask_spec = P(None, NODE_AXIS)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda sn, pd, so: stage1_mask(sn, pd, so, fit_dims=fit_dims,
                                        quota_depth=quota_depth),
         mesh=mesh, in_specs=(snap_spec, pods_spec, mask_spec),
-        out_specs=mask_spec, check_rep=False)
+        out_specs=mask_spec, check_vma=False)
     return fn(snap, pods, static_ok)
 
 
@@ -132,7 +131,7 @@ def shard_local_topk(mesh: Mesh, scores: jnp.ndarray, k: int
             mv, mi = topk_merge(v, i)
             return mv[..., :k], mi[..., :k]
 
-    fn = shard_map(per_shard, mesh=mesh,
-                   in_specs=P(None, NODE_AXIS),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(per_shard, mesh=mesh,
+                       in_specs=P(None, NODE_AXIS),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(scores)

@@ -8,6 +8,7 @@ allocator) can reuse them without a circular import.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from koordinator_tpu.snapshot.schema import PodBatch
@@ -58,7 +59,10 @@ def segment_prefix_ok(seg: jnp.ndarray, earlier: jnp.ndarray,
     """
     same = seg[:, None] == seg[None, :]                         # [P, P]
     mask = (same & earlier).astype(req.dtype)
-    cum_excl = mask @ req                                       # [P, R]
+    # HIGHEST: at default precision the MXU rounds the f32 requests
+    # (milli-CPU, MiB) to bf16, and the commit then over-admits
+    cum_excl = jnp.matmul(mask, req,
+                          precision=jax.lax.Precision.HIGHEST)  # [P, R]
     seg_c = jnp.clip(seg, 0, num_segments - 1)
     ok = jnp.all(base_used[seg_c] + cum_excl + req <= limit[seg_c] + EPS,
                  axis=-1)

@@ -43,6 +43,7 @@ from koordinator_tpu.utils.httpserver import (
     QuietJsonHandler,
 )
 
+from koordinator_tpu.compilecache import counters as compile_counters
 from koordinator_tpu.metrics import kernel_timer
 from koordinator_tpu.obs import phases as obs_phases
 from koordinator_tpu.obs.memwatch import MemWatch
@@ -66,15 +67,28 @@ from koordinator_tpu.utils.sync import guarded_by
 log = logging.getLogger(__name__)
 
 
+def _spanned_devices(snap: ClusterSnapshot) -> int:
+    """How many devices the snapshot's node columns span (1 for host
+    arrays, which run on the default device)."""
+    sharding = getattr(snap.nodes.allocatable, "sharding", None)
+    return len(sharding.device_set) if sharding is not None else 1
+
+
 @guarded_by(
     _inflight="_lock",
+    _compiled_before="_lock",
     _seq="_lock",
     timeouts="_lock",
     timeout="publish-once",
     metrics="publish-once",
 )
 class SchedulerMonitor:
-    """Per-batch cycle watchdog."""
+    """Per-batch cycle watchdog. A cycle trips it when its elapsed time,
+    less the XLA compile seconds spent on the cycle's own thread,
+    exceeds `timeout`: a cold compile (39 s for the node-sharded
+    full-gate program on a v5e-4) is not a device stall, and a stall
+    that overlaps another thread's compile still trips. Start and close
+    a cycle on the same thread."""
 
     def __init__(self, timeout_seconds: float = 30.0,
                  metrics: Optional[SchedulerMetrics] = None):
@@ -83,31 +97,40 @@ class SchedulerMonitor:
         self.metrics = metrics
         self._lock = threading.Lock()
         self._inflight: Dict[int, float] = {}
+        self._compiled_before: Dict[int, float] = {}
         self._seq = 0
+        compile_counters.install()
 
     def start_cycle(self, now: Optional[float] = None) -> int:
         now = time.monotonic() if now is None else now
+        compiled = compile_counters.thread_compile_seconds()
         with self._lock:
             self._seq += 1
             self._inflight[self._seq] = now
+            self._compiled_before[self._seq] = compiled
             return self._seq
 
-    def complete_cycle(self, token: int,
-                       now: Optional[float] = None) -> float:
+    def complete_cycle(self, token: int, now: Optional[float] = None
+                       ) -> Tuple[float, bool]:
+        """Close the cycle: (elapsed seconds, whether it stalled)."""
         now = time.monotonic() if now is None else now
+        compiled = compile_counters.thread_compile_seconds()
         with self._lock:
             started = self._inflight.pop(token, now)
+            compiled -= self._compiled_before.pop(token, compiled)
             elapsed = now - started
-            if elapsed > self.timeout:
+            stalled = elapsed - compiled > self.timeout
+            if stalled:
                 # inside the lock: concurrent sidecar cycles would
                 # otherwise lose timeout increments
                 self.timeouts += 1
-        if elapsed > self.timeout:
+        if stalled:
             if self.metrics is not None:
                 self.metrics.scheduling_timeout.labels("default").inc()
-            log.warning("scheduling cycle exceeded %.0fs: %.2fs",
-                        self.timeout, elapsed)
-        return elapsed
+            log.warning("scheduling cycle exceeded %.0fs: %.2fs "
+                        "(%.2fs of it compiling)", self.timeout, elapsed,
+                        compiled)
+        return elapsed, stalled
 
     def overdue(self, now: Optional[float] = None) -> List[int]:
         now = time.monotonic() if now is None else now
@@ -720,7 +743,7 @@ class SchedulerService:
         # SURVIVING jax devices; None = trust the runtime's view. The
         # mesh-shrink rung rebuilds its mesh over exactly this list.
         self.device_health: Optional[Callable[[], list]] = None
-        self._last_mesh_size = len(jax.devices())
+        self._last_mesh_size = 1
         self._cycle_state = LadderState()
         self.last_health_word = 0
         self.last_quarantined_pods: Optional[np.ndarray] = None
@@ -1143,7 +1166,8 @@ class SchedulerService:
                 pods, meshlib.padded_node_count(n_real, mesh))
             self._last_mesh_size = len(devs)
         else:
-            self._last_mesh_size = len(self.surviving_devices())
+            # the normal rung runs where the published snapshot lives
+            self._last_mesh_size = _spanned_devices(snap)
         if state.cascade_off:
             kwargs = dict(kwargs, cascade=False)
         if self._forced_chunks is not None:
@@ -1452,8 +1476,9 @@ class SchedulerService:
                 "%d pod(s) quarantined", word, ",".join(defects),
                 n_bad_nodes, n_bad_pods)
         self.last_quarantined_pods = pod_bad_np
-        self.last_elapsed = elapsed = self.monitor.complete_cycle(token)
-        if elapsed > self.monitor.timeout:
+        elapsed, stalled = self.monitor.complete_cycle(token)
+        self.last_elapsed = elapsed
+        if stalled:
             # the stall completed, but the NEXT cycle runs degraded:
             # a watchdog trip is a classified failure like any other
             self.metrics.failures_classified.labels(
@@ -1566,7 +1591,6 @@ class SchedulerService:
         (also kept on `last_recovery`) with the per-epoch results."""
         if self.journal is None:
             raise RuntimeError("recover() needs a commit journal")
-        from koordinator_tpu.compilecache import counters as compile_counters
 
         t0 = time.monotonic()
         t0_ns = time.monotonic_ns()

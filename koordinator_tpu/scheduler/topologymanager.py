@@ -40,6 +40,7 @@ from __future__ import annotations
 import functools
 from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -80,7 +81,8 @@ def capacity_hints(free_z: jnp.ndarray, req: jnp.ndarray,
     masks = jnp.asarray(masks_np)                            # [M, Z]
     popcnt = jnp.asarray(popcnt_np)                          # [M]
     avail = jnp.einsum("pzd,mz->pmd", free_z * valid[:, :, None],
-                       masks.astype(free_z.dtype))           # [P, M, D]
+                       masks.astype(free_z.dtype),
+                       precision=jax.lax.Precision.HIGHEST)  # [P, M, D]
     fit = jnp.all(avail + EPS >= req[:, None, :], axis=-1)   # [P, M]
     # mask must lie within the node's valid zones
     inside = ~jnp.any(masks[None] & ~valid[:, None, :], axis=-1)
@@ -161,7 +163,8 @@ def resolve(fit: jnp.ndarray, pref: jnp.ndarray, policy: jnp.ndarray,
     # strategy key per mask: total free CPU over the mask's zones,
     # normalised to [0, 1); most-allocated prefers the least-free mask
     mask_free = jnp.einsum("pz,mz->pm", free_cpu_z,
-                           masks.astype(free_cpu_z.dtype))
+                           masks.astype(free_cpu_z.dtype),
+                           precision=jax.lax.Precision.HIGHEST)
     denom = jnp.maximum(jnp.max(mask_free, axis=-1, keepdims=True), 1.0)
     strat = mask_free / (denom * (1.0 + EPS))
     if strategy != "most":

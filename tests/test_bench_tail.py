@@ -65,21 +65,6 @@ def test_bench_has_single_max_tail_env_read(bench_mod):
     assert len(reads) == 1, reads
 
 
-def test_stamped_line_always_carries_staleness(bench_mod):
-    """The one constructor for surfaced stamped lines sets the full
-    provenance set unconditionally (satellite: no stamped line without
-    a stale marker ever again)."""
-    out = bench_mod._stamped_line({"metric": "m", "value": 1.0},
-                                  "2026-01-01T00:00:00+00:00",
-                                  age=7200.0, stale_after=3600.0)
-    assert out["stamped_capture"] is True
-    assert out["stale_capture"] is True
-    assert out["stamped_age_seconds"] == 7200
-    fresh = bench_mod._stamped_line({"metric": "m"}, "t", age=10.0,
-                                    stale_after=3600.0)
-    assert fresh["stale_capture"] is False
-
-
 # --- run_with_ladder: the bench's device-lost recovery rung (ISSUE 14) -----
 
 def test_ladder_device_lost_retries_on_a_shrunk_device_set(bench_mod,
@@ -189,3 +174,30 @@ def test_bench_cost_stamp_is_opt_in_and_spliced(bench_mod):
     assert len(reads) == 1, reads
     assert "**cost_stamp," in src
     assert "flagship_stamp" in src
+
+
+def test_benchdiff_proxy_runs_without_a_persistent_cache(bench_mod,
+                                                          monkeypatch,
+                                                          tmp_path):
+    """An ambient JAX_COMPILATION_CACHE_DIR (the chip machine sets one)
+    must not turn the proxy line's `cache` stamp, part of benchdiff's
+    join key, from the baseline's "cold" into hit/miss."""
+    import jax
+
+    from tools import benchdiff
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    seen = {}
+
+    def fake_run(**_kw):
+        seen["dir"] = jax.config.jax_compilation_cache_dir
+        seen["env"] = bench_mod.os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        return {"metric": "proxy"}
+
+    monkeypatch.setattr(bench_mod, "run_northstar", fake_run)
+    try:
+        assert benchdiff.proxy_lines() == [{"metric": "proxy"}]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", None)
+    assert seen == {"dir": None, "env": None}

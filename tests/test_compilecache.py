@@ -4,15 +4,16 @@ served), and the compile-counter-backed zero-recompile pins — a second
 run, a restart recovery, and a mesh-shrink failover against a warmed
 cache dir must compile zero programs.
 
-The persistent cache is STRICTLY OPT-IN (tests/conftest.py keeps it
-disabled: XLA:CPU artifacts segfault across live-migrating hosts).
-Every test here activates it only against a fresh tmp dir — artifacts
-are written and read by THIS process on THIS machine — and the fixture
-detaches the process-global config afterwards.
+The suite keeps the persistent cache disabled (tests/conftest.py:
+XLA:CPU artifacts segfault across live-migrating hosts). Every test
+here activates it only against a fresh tmp dir — artifacts are written
+and read by THIS process on THIS machine — and the fixture detaches
+the process-global config afterwards.
 """
 
 import json
 import os
+import threading
 import types
 
 import numpy as np
@@ -39,7 +40,7 @@ N, P = 16, 32
 
 
 @pytest.fixture()
-def cache_dir(tmp_path):
+def cache_dir(tmp_path, monkeypatch):
     """A fresh cache dir; teardown re-disables the process-global
     persistent cache (the conftest invariant) and drops jax's
     once-per-process cache singleton so later tests can't read it.
@@ -49,7 +50,11 @@ def cache_dir(tmp_path):
     run without ever being written to this test's dir — and the warm
     run would then miss on it."""
     jax.clear_caches()
-    yield str(tmp_path / "cc")
+    path = str(tmp_path / "cc")
+    # placed from outside, as on a deployment: every CompileCache and
+    # enable_persistent_cache() of the test lands here
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+    yield path
     jax.config.update("jax_compilation_cache_dir", None)
     _reset_jax_persistent_cache()
 
@@ -155,7 +160,7 @@ def test_abstract_digest_sees_shape_dtype_and_path():
 
 def test_corrupt_manifest_set_aside_and_discarded_loudly(cache_dir):
     os.makedirs(cache_dir)
-    cache = CompileCache(cache_dir, fingerprint="a" * 64)
+    cache = CompileCache(fingerprint="a" * 64)
     with open(cache.manifest_path, "w") as f:
         f.write("{torn json")
     cache.activate()
@@ -169,14 +174,14 @@ def test_corrupt_manifest_set_aside_and_discarded_loudly(cache_dir):
 
 
 def test_stale_fingerprint_entries_discarded_never_served(cache_dir):
-    c1 = CompileCache(cache_dir, fingerprint="a" * 64).activate()
+    c1 = CompileCache(fingerprint="a" * 64).activate()
     try:
         assert c1.ensure("prog", lambda: "exe", key="k1") == "miss"
         assert c1.lookup("k1") is not None
     finally:
         c1.deactivate()
     # contract fingerprint moved -> the entry is dropped, loudly
-    c2 = CompileCache(cache_dir, fingerprint="b" * 64).activate()
+    c2 = CompileCache(fingerprint="b" * 64).activate()
     try:
         assert c2.lookup("k1") is None
         assert c2.manifest["entries"] == {}
@@ -184,7 +189,7 @@ def test_stale_fingerprint_entries_discarded_never_served(cache_dir):
     finally:
         c2.deactivate()
     # same fingerprint -> still trusted
-    c3 = CompileCache(cache_dir, fingerprint="a" * 64).activate()
+    c3 = CompileCache(fingerprint="a" * 64).activate()
     try:
         assert c3.lookup("k1") is not None and not c3.discarded
     finally:
@@ -192,7 +197,7 @@ def test_stale_fingerprint_entries_discarded_never_served(cache_dir):
 
 
 def test_jax_version_and_backend_staleness(cache_dir):
-    c1 = CompileCache(cache_dir, fingerprint="a" * 64).activate()
+    c1 = CompileCache(fingerprint="a" * 64).activate()
     try:
         c1.ensure("prog", lambda: "exe", key="k1")
     finally:
@@ -202,7 +207,7 @@ def test_jax_version_and_backend_staleness(cache_dir):
     raw["entries"]["k1"]["jax_version"] = "0.0.0"
     with open(os.path.join(cache_dir, "manifest.json"), "w") as f:
         json.dump(raw, f)
-    c2 = CompileCache(cache_dir, fingerprint="a" * 64).activate()
+    c2 = CompileCache(fingerprint="a" * 64).activate()
     try:
         assert c2.lookup("k1") is None
         assert any("jax 0.0.0" in reason for _, reason in c2.discarded)
@@ -211,7 +216,7 @@ def test_jax_version_and_backend_staleness(cache_dir):
 
 
 def test_ensure_memoizes_per_key(cache_dir):
-    cache = CompileCache(cache_dir, fingerprint="a" * 64).activate()
+    cache = CompileCache(fingerprint="a" * 64).activate()
     try:
         calls = {"n": 0}
 
@@ -234,7 +239,7 @@ def test_jax_event_names_still_fire(cache_dir):
     """Pin the jax.monitoring event names counters.py listens on: with
     a cache dir active, a fresh compile fires a persistent-cache MISS;
     the same computation after clear_caches() fires a HIT."""
-    cache = CompileCache(cache_dir).activate()
+    cache = CompileCache().activate()
     try:
         x = np.arange(7.0, dtype=np.float32)
         with counters.watch() as w1:
@@ -255,14 +260,14 @@ def test_precompile_second_run_compiles_nothing(cache_dir):
     program must come back from the persistent cache with zero XLA
     compilations."""
     ws = small_ws()
-    c1 = CompileCache(cache_dir).activate()
+    c1 = CompileCache().activate()
     try:
         r1 = precompile.warm(c1, ws)
         assert r1["programs"] >= 1 and r1["miss"] == r1["programs"]
     finally:
         c1.deactivate()
     jax.clear_caches()
-    c2 = CompileCache(cache_dir).activate()
+    c2 = CompileCache().activate()
     try:
         with counters.watch() as w:
             r2 = precompile.warm(c2, ws)
@@ -295,7 +300,7 @@ def test_service_warm_start_and_recovery_compile_nothing(tmp_path,
     snap, pods = service_inputs(5)
     jpath = str(tmp_path / "j.bin")
 
-    c1 = CompileCache(cache_dir)
+    c1 = CompileCache()
     svc = make_service(c1, journal=CommitJournal(jpath))
     try:
         svc.publish(snap)
@@ -306,7 +311,7 @@ def test_service_warm_start_and_recovery_compile_nothing(tmp_path,
 
     # "restart": drop every in-process executable, fresh handles
     jax.clear_caches()
-    c2 = CompileCache(cache_dir)
+    c2 = CompileCache()
     svc2 = make_service(c2, journal=CommitJournal(jpath))
     try:
         svc2.publish(snap)
@@ -333,7 +338,7 @@ def test_mesh_shrink_rung_reuses_cached_executable(cache_dir):
         pytest.skip("needs >= 2 devices (conftest forces 8 on CPU)")
     snap, pods = service_inputs(7)
 
-    c1 = CompileCache(cache_dir)
+    c1 = CompileCache()
     svc = make_service(c1)
     try:
         svc.ladder.level = DegradationLadder.L_MESH_SHRINK
@@ -344,7 +349,7 @@ def test_mesh_shrink_rung_reuses_cached_executable(cache_dir):
         c1.deactivate()
 
     jax.clear_caches()
-    c2 = CompileCache(cache_dir)
+    c2 = CompileCache()
     svc2 = make_service(c2)
     try:
         svc2.ladder.level = DegradationLadder.L_MESH_SHRINK
@@ -356,3 +361,59 @@ def test_mesh_shrink_rung_reuses_cached_executable(cache_dir):
         np.testing.assert_array_equal(got, want)
     finally:
         c2.deactivate()
+
+
+# --- where the cache lives (compilecache.persistent_cache_dir) -----------
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_cache_dir_is_the_environments_else_the_repos(from_env, tmp_path,
+                                                      monkeypatch):
+    from koordinator_tpu.compilecache import persistent_cache_dir
+
+    if from_env:
+        want = str(tmp_path / "env-cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+    assert persistent_cache_dir() == want
+    assert CompileCache(fingerprint="a" * 64).path == want
+
+
+def test_enable_persistent_cache_points_jax_at_it(cache_dir):
+    from koordinator_tpu.compilecache import enable_persistent_cache
+
+    assert enable_persistent_cache() == cache_dir
+    assert jax.config.jax_compilation_cache_dir == cache_dir
+    assert os.path.isdir(cache_dir)
+
+
+def test_activate_lands_in_the_environments_dir(cache_dir):
+    cache = CompileCache(fingerprint="a" * 64).activate()
+    try:
+        cache.ensure("prog", lambda: "exe", key="k1")
+        assert jax.config.jax_compilation_cache_dir == cache_dir
+        assert cache.manifest_path == os.path.join(cache_dir,
+                                                   "manifest.json")
+        assert os.path.exists(cache.manifest_path)
+    finally:
+        cache.deactivate()
+
+
+def test_thread_compile_seconds_counts_only_the_compiling_thread():
+    counters.install()
+    seen = {}
+
+    def compile_fresh():
+        before = counters.thread_compile_seconds()
+        jax.jit(lambda x: x * 3.0 + 17.0)(
+            np.ones(13, np.float32)).block_until_ready()
+        seen["delta"] = counters.thread_compile_seconds() - before
+
+    main_before = counters.thread_compile_seconds()
+    worker = threading.Thread(target=compile_fresh)
+    worker.start()
+    worker.join()
+    assert seen["delta"] > 0
+    assert counters.thread_compile_seconds() == main_before

@@ -94,7 +94,7 @@ def test_monitor_flags_slow_cycles():
     mon = SchedulerMonitor(timeout_seconds=1.0)
     t = mon.start_cycle(now=0.0)
     assert mon.overdue(now=2.5) == [t]
-    assert mon.complete_cycle(t, now=3.0) == 3.0
+    assert mon.complete_cycle(t, now=3.0) == (3.0, True)
     assert mon.timeouts == 1
     t2 = mon.start_cycle(now=10.0)
     mon.complete_cycle(t2, now=10.2)

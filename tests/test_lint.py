@@ -376,46 +376,11 @@ def test_repo_pb2_stamps_current():
         [f.render() for f in new + suppressed]
 
 
-# --- satellite: bench stamped-capture staleness --------------------------
-
-def test_bench_stale_capture_flag(tmp_path, monkeypatch, capsys):
-    import datetime
-
-    import bench
-
-    art = tmp_path / "cap.json"
-    monkeypatch.setattr(bench, "CAPTURE_ARTIFACT", str(art))
-
-    def write_artifact(age_seconds, n_lines=1):
-        at = (datetime.datetime.now(datetime.timezone.utc)
-              - datetime.timedelta(seconds=age_seconds)).isoformat()
-        art.write_text(json.dumps(
-            {"captured_at": at,
-             "lines": [{"metric": f"m{i}", "value": 1.0}
-                       for i in range(n_lines)]}))
-
-    write_artifact(30)
-    assert bench.surface_stamped_capture()
-    fresh = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert fresh["stamped_capture"] is True
-    assert fresh["stale_capture"] is False
-
-    # EVERY stamped line of a multi-line artifact carries the full
-    # provenance set — the r05 tail surfaced 10 h-old captures whose
-    # metric lines had no stale marker
-    write_artifact(4 * 3600, n_lines=3)   # older than the 1 h default
-    assert bench.surface_stamped_capture()
-    out_lines = [json.loads(l) for l in
-                 capsys.readouterr().out.strip().splitlines()]
-    assert len(out_lines) == 3
-    for stale in out_lines:
-        assert stale["stamped_capture"] is True
-        assert stale["stale_capture"] is True
-        assert stale["stamped_age_seconds"] >= 3600
-
-    # threshold is configurable
-    monkeypatch.setenv("BENCH_STAMP_STALE_AFTER", str(10 * 3600))
-    write_artifact(4 * 3600)
-    assert bench.surface_stamped_capture()
-    ok = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert ok["stale_capture"] is False
+def test_project_skips_what_gitignore_lists(tmp_path):
+    (tmp_path / ".gitignore").write_text("# scratch\nout/\n*.pyc\n")
+    (tmp_path / "kept.py").write_text("x = 1\n")
+    (tmp_path / "out").mkdir()
+    (tmp_path / "out" / "copy.py").write_text("x = (\n")
+    project = Project(str(tmp_path))
+    assert [m.relpath for m in project.modules] == ["kept.py"]
+    assert not project.parse_errors

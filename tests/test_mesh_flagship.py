@@ -71,6 +71,29 @@ def test_snapshot_sharding_derived_from_specs():
     assert sh.version.spec == jax.sharding.PartitionSpec()
 
 
+def test_service_commit_keeps_the_published_layout():
+    """A commit returns every snapshot leaf in the layout shard_snapshot
+    published it in (zero-size aux columns included), so the second
+    cycle reuses the first cycle's program instead of recompiling it."""
+    from koordinator_tpu.compilecache import counters
+    from koordinator_tpu.scheduler.frameworkext import SchedulerService
+
+    mesh = make_mesh(jax.devices()[:4])
+    snap = synthetic.synthetic_cluster(32, seed=4, num_quotas=4)
+    pods = synthetic.synthetic_pods(64, seed=5, num_quotas=4)
+    svc = SchedulerService(num_rounds=2, k_choices=4)
+    svc.publish(shard_snapshot(snap, mesh))
+    published = jax.tree_util.tree_map(lambda x: x.sharding,
+                                       svc.store.current())
+    svc.schedule(pods)
+    committed = jax.tree_util.tree_map(lambda x: x.sharding,
+                                       svc.store.current())
+    assert committed == published
+    with counters.watch() as w:
+        svc.schedule(pods)
+    assert w.backend_compiles == 0
+
+
 def test_result_sharding_derived():
     mesh = make_mesh(jax.devices())
     rs = struct_sharding("ScheduleResult", mesh)

@@ -224,7 +224,7 @@ def proxy_lines() -> List[dict]:
     # pin the protocol: ambient BENCH_* knobs would change the join key
     # (cache stamp, cascade, tail mode, ...) or the timed program, and
     # the baseline was stamped with none of them set
-    for knob in ("BENCH_COMPILE_CACHE", "BENCH_CASCADE",
+    for knob in ("JAX_COMPILATION_CACHE_DIR", "BENCH_CASCADE",
                  "BENCH_TAIL_MODE", "BENCH_DEVICES", "BENCH_MESH_PODS",
                  "BENCH_PACK_SNAPSHOT", "BENCH_TRACE", "BENCH_APPROX",
                  "BENCH_K", "BENCH_TAIL_K", "BENCH_ROUNDS",
@@ -233,6 +233,9 @@ def proxy_lines() -> List[dict]:
         os.environ.pop(knob, None)
     import bench
 
+    # no persistent cache, so the line's `cache` stamp reads "cold" as
+    # the baseline's does (jax read the env var at import)
+    bench.jax.config.update("jax_compilation_cache_dir", None)
     bench.ensure_platform()
     line = bench.run_northstar(full_gate=False, **PROXY_SHAPE)
     line.pop("arrays", None)

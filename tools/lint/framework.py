@@ -33,6 +33,19 @@ DEFAULT_EXCLUDES = (
 )
 
 
+def gitignored(root: str) -> Tuple[str, ...]:
+    """The plain paths (no glob patterns) the root's .gitignore lists:
+    what git never commits is never judged either."""
+    try:
+        with open(os.path.join(root, ".gitignore"), encoding="utf-8") as f:
+            lines = f.read().splitlines()
+    except OSError:
+        return ()
+    return tuple(line.strip().strip("/") for line in lines
+                 if line.strip() and not line.startswith(("#", "!"))
+                 and not any(c in line for c in "*?["))
+
+
 @dataclass(frozen=True)
 class Finding:
     """One lint violation.
@@ -92,7 +105,7 @@ class Project:
         self.by_dotted: Dict[str, Module] = {}
         self.proto_files: List[str] = []   # root-relative
         self.parse_errors: List[Finding] = []
-        self._load(excludes)
+        self._load(tuple(excludes) + gitignored(self.root))
 
     def _load(self, excludes: Sequence[str]) -> None:
         norm_excludes = tuple(e.replace("/", os.sep) for e in excludes)
@@ -192,7 +205,8 @@ def cached_project(root: str,
     repeat runs over an unchanged tree skip the os.walk + ast.parse
     cost entirely."""
     key = (os.path.abspath(root), tuple(excludes))
-    norm_excludes = tuple(e.replace("/", os.sep) for e in excludes)
+    norm_excludes = tuple(e.replace("/", os.sep)
+                          for e in key[1] + gitignored(key[0]))
     sig = _tree_signature(key[0], norm_excludes)
     hit = _PROJECT_CACHE.get(key)
     if hit is not None and hit[0] == sig:

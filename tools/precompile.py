@@ -1,7 +1,9 @@
 """CI/deploy cache warmer: enumerate the configured working set and
-AOT-compile every program into a compile-cache dir, so the first REAL
-scheduling cycle of the next process over that dir (same host — see
-tests/conftest.py on artifact portability) traces but never compiles.
+AOT-compile every program into the persistent compile cache
+(JAX_COMPILATION_CACHE_DIR when set, else <repo>/.jax_cache), so the
+first REAL scheduling cycle of the next process over that dir (same
+host — see tests/conftest.py on artifact portability) traces but never
+compiles.
 
 The enumeration is the koordshape-registry walk in
 koordinator_tpu/compilecache/precompile.py: the flagship cycle per
@@ -10,9 +12,8 @@ padded exactly as the service's mesh-shrink failover pads it, and the
 canonical donated tail-compaction form.
 
 Usage:
-  python tools/precompile.py --cache-dir /path/to/cache \\
-      [--devices N] [--size P=256 --size N=128 ...] [--guards] \\
-      [--no-tail] [--cascade on|off|both] [--json]
+  python tools/precompile.py [--devices N] [--size P=256 --size N=128 ...] \\
+      [--guards] [--no-tail] [--cascade on|off|both] [--json]
 
 Exit code 0 on success; the report (per-program hit/warm/miss lines +
 totals) goes to stdout. `bench.py BENCH_PRECOMPILE=1` wraps the same
@@ -45,9 +46,6 @@ def parse_sizes(pairs):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--cache-dir", required=True,
-                    help="compile-cache dir to warm (created if absent; "
-                         "SAME-HOST use only)")
     ap.add_argument("--devices", type=int, default=None,
                     help="top of the shrunk-mesh ladder "
                          "(default: all visible devices)")
@@ -77,11 +75,11 @@ def main(argv=None) -> int:
         cascade_forms=cascade_forms,
         tail=None if args.no_tail else dict(precompile.DEFAULT_TAIL),
         guards=args.guards)
-    cache = CompileCache(args.cache_dir)
+    cache = CompileCache()
     report = precompile.warm(
         cache, ws,
         log_fn=None if args.json else lambda s: print(s, flush=True))
-    report["cache_dir"] = args.cache_dir
+    report["cache_dir"] = cache.path
     report["fingerprint"] = cache.fingerprint[:16]
     if args.json:
         print(json.dumps(report))
@@ -89,7 +87,7 @@ def main(argv=None) -> int:
         print(f"precompile: {report['programs']} program(s) "
               f"({report['hit']} hit / {report['warm']} warm / "
               f"{report['miss']} miss) in {report['seconds']}s "
-              f"-> {args.cache_dir}")
+              f"-> {cache.path}")
     return 0
 
 

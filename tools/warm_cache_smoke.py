@@ -60,7 +60,7 @@ def make_inputs(seed: int):
 
 
 def make_service(workdir: str, journal_name: str) -> SchedulerService:
-    cache = CompileCache(os.path.join(workdir, "cache"))
+    cache = CompileCache()  # JAX_COMPILATION_CACHE_DIR, from run_child
     journal = CommitJournal(os.path.join(workdir, journal_name))
     svc = SchedulerService(metrics=SchedulerMetrics(Registry()),
                            num_rounds=2, k_choices=4, guards=False,
@@ -103,7 +103,9 @@ def child(mode: str, workdir: str, seed: int) -> int:
 
 
 def run_child(mode: str, workdir: str, seed: int) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # the cache under test is placed from outside, as on a deployment
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=os.path.join(workdir, "cache"))
     proc = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child", mode,
          workdir, str(seed)],
