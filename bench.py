@@ -552,29 +552,10 @@ def run_northstar(full_gate: bool = False, num_pods: int = None,
     if pack_on:
         from koordinator_tpu.snapshot import packing
         pack_stats = packing.packed_savings(snap0, pods)
-    # BENCH_TRACE=<dir>: koordtrace capture of this line. The warmup and
-    # every timed pass become spans in one ring (obs.trace.Tracer), the
-    # Chrome/JSONL dump lands in <dir>, and the line stamps the trace
-    # path + cycle p50/p99 computed from the SAME span records the dump
-    # contains — the stamped latency and the Perfetto view can't drift.
-    trace_dir = (os.environ.get("BENCH_TRACE") or "").strip()
-    tracer = None
-    if trace_dir:
-        from koordinator_tpu.obs.trace import Tracer
-        tracer = Tracer()
-
-    def bench_span(name):
-        if tracer is None:
-            from koordinator_tpu.obs.trace import NOOP_SPAN
-            return NOOP_SPAN
-        return tracer.span(name)
-
-    from koordinator_tpu.obs import phases as obs_phases
     from koordinator_tpu.compilecache import counters as compile_counters
     warm_t0 = time.perf_counter()
     with compile_counters.watch() as warm_watch:
-        with bench_span(obs_phases.SPAN_BENCH_WARMUP):
-            out = full_pass(snap0, counts0)
+        out = full_pass(snap0, counts0)
     warm_start_s = time.perf_counter() - warm_t0
     del out
     if not jax.config.jax_compilation_cache_dir:
@@ -588,34 +569,9 @@ def run_northstar(full_gate: bool = False, num_pods: int = None,
     snap1 = put_snap(checked_snap(7))
     counts1 = put_repl(tuple(getattr(pods, f) for f in core.COUNT_FIELDS))
     t0 = time.perf_counter()
-    with bench_span(obs_phases.SPAN_BENCH_CYCLE):
-        (snap, counts, assign, left_after_sweep, left_final, never_retried,
-         passes) = full_pass(snap1, counts1)
+    (snap, counts, assign, left_after_sweep, left_final, never_retried,
+     passes) = full_pass(snap1, counts1)
     elapsed = time.perf_counter() - t0
-
-    # traced runs may ask for extra steady-state reps (fresh snapshot
-    # each; the donated buffers are consumed per pass) so the stamped
-    # p50/p99 rest on more than one sample. `elapsed` stays the FIRST
-    # pass — the protocol metric is untouched by the rep knob.
-    trace_stamp = {}
-    if tracer is not None:
-        for rep in range(max(int(os.environ.get("BENCH_TRACE_REPS",
-                                                "1")), 1) - 1):
-            snap_r = put_snap(checked_snap(11 + rep))
-            counts_r = put_repl(tuple(getattr(pods, f)
-                                      for f in core.COUNT_FIELDS))
-            with bench_span(obs_phases.SPAN_BENCH_CYCLE):
-                full_pass(snap_r, counts_r)
-        durs = tracer.durations_s(obs_phases.SPAN_BENCH_CYCLE)
-        from koordinator_tpu.obs import export as obs_export
-        paths = obs_export.dump(tracer, out_dir=trace_dir,
-                                prefix=f"bench_{metric}",
-                                formats=("chrome", "jsonl"))
-        trace_stamp = {
-            "trace": paths[0],
-            "cycle_p50": round(float(np.quantile(durs, 0.5)), 4),
-            "cycle_p99": round(float(np.quantile(durs, 0.99)), 4),
-        }
 
     placed = int((assign >= 0).sum())
     if never_retried > 0:
@@ -684,9 +640,6 @@ def run_northstar(full_gate: bool = False, num_pods: int = None,
         # mesh-shrink rung); `devices`/`mesh` below then carry the
         # SHRUNK size, so the line is self-describing
         **({"recovered": recovered} if recovered else {}),
-        # present ONLY on a traced run (BENCH_TRACE=dir): where the
-        # Chrome dump landed + cycle p50/p99 from the same span records
-        **trace_stamp,
         "devices": len(devices),
         # the mesh stamp makes a 4-device line self-describing (1x4 vs
         # 2x2); absent on single-device lines so trajectories stay

@@ -12,8 +12,10 @@ Names, not enums, because they end up verbatim in Chrome trace-event
 JSON and in the `scheduler_cycle_phase_seconds{phase=...}` label set.
 Kernel phases carry the `koord/` prefix (they appear inside XLA
 profiler streams next to XLA-internal names and need a grep-able
-namespace); host cycle spans are bare (they only ever appear in
-koordtrace's own buffer).
+namespace); host cycle spans are bare. Host spans land in koordtrace's
+ring and, while a span is open, as a `TraceAnnotation` of the same
+name on the profiler's host plane (obs/trace.py), beside the device
+ops of a profiler capture.
 """
 
 # --- device/kernel phases (named_scope / TraceAnnotation labels) ---
@@ -44,13 +46,22 @@ PHASE_TAIL_LOOP = "koord/tail_loop"
 
 SPAN_CYCLE = "cycle"
 SPAN_ADMIT = "admit"
+# inside admit: the cpu_amplification > 1 check (a device op and its
+# readback) and _prepare_batch's auto-pack
+SPAN_AMP_CHECK = "amp_check"
+SPAN_PREPARE_BATCH = "prepare_batch"
 SPAN_GUARD_SCAN = "guard_scan"
 SPAN_ENSURE_CACHED = "ensure_cached"
 SPAN_DISPATCH = "dispatch"
 SPAN_DEVICE_WAIT = "device_wait"
+# auto-pack's inverse-permutation gather back to the caller's pod order
+SPAN_UNPACK = "unpack"
 SPAN_JOURNAL_APPEND = "journal_append"
 SPAN_PUBLISH = "publish"
 SPAN_CHECKPOINT = "checkpoint"
+# schedule()'s post-commit block after the cycle span: health word,
+# metrics, the gang_failed readback, error dispatch
+SPAN_FINALIZE = "finalize"
 SPAN_BACKOFF = "backoff"
 SPAN_RECOVER = "recover"
 SPAN_RECOVER_REPLAY = "recover_replay"
@@ -60,10 +71,6 @@ SPAN_RECOVER_COMPILE = "recover_compile"
 EVENT_QUARANTINE = "quarantine"
 EVENT_LADDER_TRANSITION = "ladder_transition"
 EVENT_RETRY = "retry"
-
-# bench spans (bench.py BENCH_TRACE mode)
-SPAN_BENCH_WARMUP = "bench_warmup"
-SPAN_BENCH_CYCLE = "bench_cycle"
 
 KERNEL_PHASES = frozenset({
     PHASE_SCHEDULE_BATCH,
@@ -82,13 +89,17 @@ KERNEL_PHASES = frozenset({
 HOST_SPANS = frozenset({
     SPAN_CYCLE,
     SPAN_ADMIT,
+    SPAN_AMP_CHECK,
+    SPAN_PREPARE_BATCH,
     SPAN_GUARD_SCAN,
     SPAN_ENSURE_CACHED,
     SPAN_DISPATCH,
+    SPAN_UNPACK,
     SPAN_DEVICE_WAIT,
     SPAN_JOURNAL_APPEND,
     SPAN_PUBLISH,
     SPAN_CHECKPOINT,
+    SPAN_FINALIZE,
     SPAN_BACKOFF,
     SPAN_RECOVER,
     SPAN_RECOVER_REPLAY,
@@ -96,8 +107,6 @@ HOST_SPANS = frozenset({
     EVENT_QUARANTINE,
     EVENT_LADDER_TRANSITION,
     EVENT_RETRY,
-    SPAN_BENCH_WARMUP,
-    SPAN_BENCH_CYCLE,
 })
 
 ALL_PHASES = KERNEL_PHASES | HOST_SPANS
@@ -111,6 +120,7 @@ CYCLE_SKELETON = (
     SPAN_GUARD_SCAN,
     SPAN_JOURNAL_APPEND,
     SPAN_PUBLISH,
+    SPAN_FINALIZE,
 )
 
 

@@ -1220,17 +1220,19 @@ class SchedulerService:
                 # shared SnapshotStore (SnapshotSyncer._rebuild,
                 # embedded compositions).
                 if not self._explicit_amp:
-                    self.schedule_kwargs["enable_amplification"] = bool(
-                        np.asarray(
-                            snap.nodes.cpu_amplification > 1.0).any())
+                    with self._span(obs_phases.SPAN_AMP_CHECK):
+                        self.schedule_kwargs["enable_amplification"] = \
+                            bool(np.asarray(
+                                snap.nodes.cpu_amplification > 1.0).any())
                 # a journaled resume (forced chunk layout) also forbids
                 # prefix packing: slicing a packed batch breaks the
                 # row-range contracts, exactly like the chunked rung
-                sched_pods, pack_kwargs, inv = self._prepare_batch(
-                    snap, pods,
-                    allow_prefix_pack=not state.chunked
-                    and (self._forced_chunks is None
-                         or self._forced_chunks <= 1))
+                with self._span(obs_phases.SPAN_PREPARE_BATCH):
+                    sched_pods, pack_kwargs, inv = self._prepare_batch(
+                        snap, pods,
+                        allow_prefix_pack=not state.chunked
+                        and (self._forced_chunks is None
+                             or self._forced_chunks <= 1))
                 if adm is not None:
                     # the trace <-> journal join at cycle granularity
                     adm["base_version"] = self.store.version
@@ -1253,11 +1255,12 @@ class SchedulerService:
                 if inv is not None:
                     # back to the CALLER's pod order before anything
                     # (hooks, error chain, debug tables) sees the result
-                    result = result.replace(
-                        **{f: getattr(result, f)[inv]
-                           for f in core.PER_POD_RESULT_FIELDS})
-                    if pod_bad is not None:
-                        pod_bad = pod_bad[inv]
+                    with self._span(obs_phases.SPAN_UNPACK):
+                        result = result.replace(
+                            **{f: getattr(result, f)[inv]
+                               for f in core.PER_POD_RESULT_FIELDS})
+                        if pod_bad is not None:
+                            pod_bad = pod_bad[inv]
                 # single D2H transfer doubles as the completion barrier
                 # (and makes the kernel timer measure device time)
                 with self._span(obs_phases.SPAN_DEVICE_WAIT):
@@ -1443,89 +1446,92 @@ class SchedulerService:
                         and pre_level != DegradationLadder.L_MESH_SHRINK:
                     self.metrics.mesh_shrink_events.inc()
                 backoff.reset()
-        self.last_ladder_state = state
-        if state.degraded or probing:
-            self.metrics.degraded_cycles.labels(state.label()).inc()
-        self.metrics.degradation_level.set(float(self.ladder.level))
-        self.metrics.mesh_size.set(float(mesh_size))
-        if self.journal is not None and replayed:
-            self.metrics.recovery_replayed.inc(replayed)
-        word = int(health[0]) if health is not None else 0
-        self.last_health_word = word
-        pod_bad_np: Optional[np.ndarray] = None
-        if word:
-            defects = guards.decode_health_word(word)
-            for name in defects:
-                self.metrics.guard_trips.labels(name).inc()
-            n_bad_nodes, n_bad_pods = int(health[1]), int(health[2])
-            if n_bad_nodes:
-                self.metrics.quarantined_inputs.labels("node").inc(
-                    n_bad_nodes)
-            if n_bad_pods:
-                self.metrics.quarantined_inputs.labels("pod").inc(
-                    n_bad_pods)
-            if pod_bad is not None:
-                pod_bad_np = np.asarray(pod_bad)
-            if self.tracer is not None:
-                self._event(obs_phases.EVENT_QUARANTINE,
-                            {"word": word, "defects": defects,
-                             "bad_nodes": n_bad_nodes,
-                             "bad_pods": n_bad_pods}, cycle=cycle_id)
-            log.warning(
-                "health guards tripped: word=0x%x (%s); %d node(s) / "
-                "%d pod(s) quarantined", word, ",".join(defects),
-                n_bad_nodes, n_bad_pods)
-        self.last_quarantined_pods = pod_bad_np
-        elapsed, stalled = self.monitor.complete_cycle(token)
-        self.last_elapsed = elapsed
-        if stalled:
-            # the stall completed, but the NEXT cycle runs degraded:
-            # a watchdog trip is a classified failure like any other
-            self.metrics.failures_classified.labels(
-                FailureClass.WATCHDOG_STALL.value).inc()
-            n_trans = len(self.ladder.transitions)
-            self.ladder.on_failure(FailureClass.WATCHDOG_STALL,
-                                   probing=False)
-            self._trace_transitions(n_trans, cycle_id)
-        # per-CALL (version, elapsed) for the calling thread: the
-        # threaded sidecar reads them after scheduling, and the shared
-        # attributes race with concurrent ingests/schedules
-        self._tls.version = version
-        self._tls.elapsed = elapsed
-        self.metrics.cycle_seconds.observe(elapsed)
-        valid = np.asarray(pods.valid)
-        placed_n = int(((assignment >= 0) & valid).sum())
-        with self._counter_lock:
-            # += on the shared counters is not atomic across threads;
-            # the threaded sidecar schedules concurrently
-            self.batches += 1
-            self.pods_placed += placed_n
-        self.metrics.pods_scheduled.labels("placed").inc(placed_n)
-        unsched = (assignment < 0) & valid
-        if pod_bad_np is not None:
-            # quarantined rows are infrastructure errors, already
-            # counted per kind above — not "unschedulable" (cluster
-            # full) rows
-            unsched &= ~pod_bad_np
-        self.metrics.pods_scheduled.labels("unschedulable").inc(
-            int(unsched.sum()))
-        self.metrics.snapshot_version.set(float(self.store.version))
-        # koordcost: the cycle committed and its counters/histograms
-        # are final — advance the leak sentinel and the SLO rings
-        if self.memwatch is not None:
-            self.memwatch.observe_cycle()
-        if self.slo is not None:
-            self.slo.observe_cycle()
-        gang_failed = np.asarray(result.gang_failed)
-        self.last_gang_failed = gang_failed
-        if gang_failed.any() and self.on_gang_failed is not None:
-            self.on_gang_failed(np.where(gang_failed)[0], result)
-        if typed_pods is not None:
-            from koordinator_tpu.scheduler.errorhandler import (
-                dispatch_batch_errors,
-            )
-            dispatch_batch_errors(self.error_dispatcher, assignment, valid,
-                                  typed_pods, infra_mask=pod_bad_np)
+        # the post-commit block: the cycle committed; what follows
+        # reads its results on the host and feeds the metrics
+        with self._span(obs_phases.SPAN_FINALIZE, cycle=cycle_id):
+            self.last_ladder_state = state
+            if state.degraded or probing:
+                self.metrics.degraded_cycles.labels(state.label()).inc()
+            self.metrics.degradation_level.set(float(self.ladder.level))
+            self.metrics.mesh_size.set(float(mesh_size))
+            if self.journal is not None and replayed:
+                self.metrics.recovery_replayed.inc(replayed)
+            word = int(health[0]) if health is not None else 0
+            self.last_health_word = word
+            pod_bad_np: Optional[np.ndarray] = None
+            if word:
+                defects = guards.decode_health_word(word)
+                for name in defects:
+                    self.metrics.guard_trips.labels(name).inc()
+                n_bad_nodes, n_bad_pods = int(health[1]), int(health[2])
+                if n_bad_nodes:
+                    self.metrics.quarantined_inputs.labels("node").inc(
+                        n_bad_nodes)
+                if n_bad_pods:
+                    self.metrics.quarantined_inputs.labels("pod").inc(
+                        n_bad_pods)
+                if pod_bad is not None:
+                    pod_bad_np = np.asarray(pod_bad)
+                if self.tracer is not None:
+                    self._event(obs_phases.EVENT_QUARANTINE,
+                                {"word": word, "defects": defects,
+                                 "bad_nodes": n_bad_nodes,
+                                 "bad_pods": n_bad_pods}, cycle=cycle_id)
+                log.warning(
+                    "health guards tripped: word=0x%x (%s); %d node(s) / "
+                    "%d pod(s) quarantined", word, ",".join(defects),
+                    n_bad_nodes, n_bad_pods)
+            self.last_quarantined_pods = pod_bad_np
+            elapsed, stalled = self.monitor.complete_cycle(token)
+            self.last_elapsed = elapsed
+            if stalled:
+                # the stall completed, but the NEXT cycle runs degraded:
+                # a watchdog trip is a classified failure like any other
+                self.metrics.failures_classified.labels(
+                    FailureClass.WATCHDOG_STALL.value).inc()
+                n_trans = len(self.ladder.transitions)
+                self.ladder.on_failure(FailureClass.WATCHDOG_STALL,
+                                       probing=False)
+                self._trace_transitions(n_trans, cycle_id)
+            # per-CALL (version, elapsed) for the calling thread: the
+            # threaded sidecar reads them after scheduling, and the shared
+            # attributes race with concurrent ingests/schedules
+            self._tls.version = version
+            self._tls.elapsed = elapsed
+            self.metrics.cycle_seconds.observe(elapsed)
+            valid = np.asarray(pods.valid)
+            placed_n = int(((assignment >= 0) & valid).sum())
+            with self._counter_lock:
+                # += on the shared counters is not atomic across threads;
+                # the threaded sidecar schedules concurrently
+                self.batches += 1
+                self.pods_placed += placed_n
+            self.metrics.pods_scheduled.labels("placed").inc(placed_n)
+            unsched = (assignment < 0) & valid
+            if pod_bad_np is not None:
+                # quarantined rows are infrastructure errors, already
+                # counted per kind above — not "unschedulable" (cluster
+                # full) rows
+                unsched &= ~pod_bad_np
+            self.metrics.pods_scheduled.labels("unschedulable").inc(
+                int(unsched.sum()))
+            self.metrics.snapshot_version.set(float(self.store.version))
+            # koordcost: the cycle committed and its counters/histograms
+            # are final — advance the leak sentinel and the SLO rings
+            if self.memwatch is not None:
+                self.memwatch.observe_cycle()
+            if self.slo is not None:
+                self.slo.observe_cycle()
+            gang_failed = np.asarray(result.gang_failed)
+            self.last_gang_failed = gang_failed
+            if gang_failed.any() and self.on_gang_failed is not None:
+                self.on_gang_failed(np.where(gang_failed)[0], result)
+            if typed_pods is not None:
+                from koordinator_tpu.scheduler.errorhandler import (
+                    dispatch_batch_errors,
+                )
+                dispatch_batch_errors(self.error_dispatcher, assignment, valid,
+                                      typed_pods, infra_mask=pod_bad_np)
         if self.flags.score_top_n > 0:
             log.info("score table:\n%s", debug_score_table(
                 snap, pods, self.cfg, self.flags.score_top_n, pod_names))

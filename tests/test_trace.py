@@ -1,10 +1,13 @@
 """koordtrace battery: the span tracer's structural contracts (ring
 overflow, nesting, thread safety, monotonic timestamps), the Chrome
 trace-event export schema Perfetto loads, `Histogram.percentile`
-against numpy.quantile, and the zero-overhead-when-disabled pin on the
-service dispatch path."""
+against numpy.quantile, the zero-overhead-when-disabled pin on the
+service dispatch path, and the spans' mirror on the profiler's host
+plane."""
 
+import glob
 import json
+import os
 import threading
 import time
 
@@ -326,3 +329,85 @@ def test_enabled_service_cycle_carries_skeleton():
     assert cycles == {0}
     for r in recs:
         assert r.name in phases.ALL_PHASES
+
+
+# --- the mirror on the profiler's host plane --------------------------------
+
+# the spans every committed cycle of a small service opens (no journal,
+# no auto-pack reorder at 16 pods: no journal_append, no unpack)
+MIRRORED = (phases.SPAN_CYCLE, phases.SPAN_ADMIT, phases.SPAN_AMP_CHECK,
+            phases.SPAN_PREPARE_BATCH, phases.SPAN_DISPATCH,
+            phases.SPAN_DEVICE_WAIT, phases.SPAN_GUARD_SCAN,
+            phases.SPAN_PUBLISH, phases.SPAN_FINALIZE)
+
+
+def host_plane_events(trace_dir, names):
+    """{name: [(start, end)] in start order} of the host-plane events
+    named in `names`, from every .xplane.pb under `trace_dir`."""
+    from jax.profiler import ProfileData
+
+    out = {}
+    for path in glob.glob(os.path.join(str(trace_dir), "**",
+                                       "*.xplane.pb"), recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        out.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return {k: sorted(v) for k, v in out.items()}
+
+
+def captured_cycles(trace_dir, trace, n):
+    """A small service, warmed outside the capture, then `n` schedule()
+    calls inside a CPU profiler capture."""
+    import jax
+
+    from koordinator_tpu.scheduler.frameworkext import SchedulerService
+    from koordinator_tpu.utils import synthetic
+
+    svc = SchedulerService(num_rounds=1, k_choices=4, trace=trace)
+    svc.publish(synthetic.synthetic_cluster(16, num_quotas=4))
+    svc.schedule(synthetic.synthetic_pods(16, num_quotas=4))
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        for i in range(n):
+            svc.schedule(synthetic.synthetic_pods(16, seed=i + 1,
+                                                  num_quotas=4))
+    finally:
+        jax.profiler.stop_trace()
+    return svc
+
+
+def test_spans_are_mirrored_on_the_profiler_host_plane(tmp_path):
+    """A traced schedule() puts each span on the profiler's host plane
+    under its own name, once per cycle, nested as the ring nests it."""
+    n = 2
+    svc = captured_cycles(tmp_path, True, n)
+    plane = host_plane_events(tmp_path, phases.HOST_SPANS)
+    ring = [r for r in svc.tracer.records() if r.cycle >= 1]
+    for name in MIRRORED:
+        assert len(plane.get(name, [])) == n, (name, plane.get(name))
+        assert sum(r.name == name for r in ring) == n, name
+    parents = {(r.name, r.parent) for r in ring
+               if r.name in MIRRORED and r.parent is not None}
+    assert (phases.SPAN_AMP_CHECK, phases.SPAN_ADMIT) in parents
+    assert (phases.SPAN_ADMIT, phases.SPAN_CYCLE) in parents
+    for child, parent in parents:
+        for (cs, ce), (ps, pe) in zip(plane[child], plane[parent]):
+            assert ps <= cs and ce <= pe, (child, parent)
+    # finalize follows its cycle's span, inside the same call
+    for (_, cycle_end), (fin_start, _) in zip(plane[phases.SPAN_CYCLE],
+                                              plane[phases.SPAN_FINALIZE]):
+        assert cycle_end <= fin_start
+
+
+def test_disabled_service_opens_no_annotation(tmp_path):
+    """trace=None mirrors nothing: the capture holds the kernel timer's
+    own annotation and no koordtrace span."""
+    captured_cycles(tmp_path, None, 1)
+    plane = host_plane_events(
+        tmp_path, phases.HOST_SPANS | {phases.PHASE_SCHEDULE_BATCH})
+    assert set(plane) == {phases.PHASE_SCHEDULE_BATCH}

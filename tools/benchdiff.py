@@ -226,7 +226,7 @@ def proxy_lines() -> List[dict]:
     # the baseline was stamped with none of them set
     for knob in ("JAX_COMPILATION_CACHE_DIR", "BENCH_CASCADE",
                  "BENCH_TAIL_MODE", "BENCH_DEVICES", "BENCH_MESH_PODS",
-                 "BENCH_PACK_SNAPSHOT", "BENCH_TRACE", "BENCH_APPROX",
+                 "BENCH_PACK_SNAPSHOT", "BENCH_APPROX",
                  "BENCH_K", "BENCH_TAIL_K", "BENCH_ROUNDS",
                  "BENCH_TAIL_ROUNDS", "BENCH_TAIL_CHUNK",
                  "BENCH_MAX_TAIL_PASSES"):

@@ -5,8 +5,9 @@ SchedulerService this stage asserts:
 
   1. SKELETON   — every committed cycle records the full host span
                   skeleton (admit -> dispatch -> device_wait ->
-                  guard_scan -> journal_append -> publish) under one
-                  shared cycle id, plus the checkpoint epilogue;
+                  guard_scan -> journal_append -> publish -> finalize)
+                  under one shared cycle id, plus the checkpoint
+                  epilogue;
   2. LOADABLE   — the Chrome dump is valid trace-event JSON (complete
                   X events with us timestamps, instant events marked
                   ph='i'), i.e. Perfetto-loadable;
