@@ -9,7 +9,9 @@ zones, G gangs, ... and a device count) the whole program set is
 enumerable ahead of time:
 
   - the flagship cycle program (core.schedule_batch, or the guarded
-    fusion when the service runs guards) per cascade form;
+    fusion when the service runs guards) per cascade form, on one
+    device with the pod batch as the one buffer the service uploads
+    (snapshot/hostbatch.py);
   - the same under every plausible SHRUNK mesh (devices, devices-1,
     ..., 1) with the node axis padded to each mesh exactly as the
     service's mesh-shrink rung pads it — so device loss fails over
@@ -42,6 +44,7 @@ import numpy as np
 from koordinator_tpu.compilecache import keys
 from koordinator_tpu.compilecache.cache import CompileCache
 from koordinator_tpu.scheduler import core
+from koordinator_tpu.snapshot import hostbatch
 from koordinator_tpu.snapshot.schema import shape_contract
 
 # --- the canonical AOT tail form ------------------------------------------
@@ -308,6 +311,10 @@ def enumerate_programs(ws: WorkSet,
                                    ws.materialize_optional)
         pods_sds = abstract_struct("PodBatch", sizes, None,
                                    ws.materialize_optional)
+        # on one device the service uploads a host batch as one buffer
+        # (snapshot/hostbatch.py); the mesh rungs take it per leaf
+        batch_sds = hostbatch.abstract(pods_sds) if mesh is None \
+            else pods_sds
         cfg_sds = abstract_struct("LoadAwareConfig", sizes, None,
                                   ws.materialize_optional)
         for cascade in ws.cascade_forms:
@@ -318,10 +325,10 @@ def enumerate_programs(ws: WorkSet,
                 label=label,
                 key=keys.cache_key(
                     label, keys.abstract_digest(
-                        (snap_sds, pods_sds, cfg_sds)),
+                        (snap_sds, batch_sds, cfg_sds)),
                     statics, mesh_axes, fingerprint=fingerprint),
                 build=functools.partial(
-                    _build_cycle, fn, snap_sds, pods_sds, cfg_sds,
+                    _build_cycle, fn, snap_sds, batch_sds, cfg_sds,
                     statics),
                 meta={"form": "cycle", "devices": d,
                       "cascade": cascade, "sizes_P": sizes["P"],

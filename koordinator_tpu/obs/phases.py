@@ -53,6 +53,9 @@ SPAN_PREPARE_BATCH = "prepare_batch"
 SPAN_GUARD_SCAN = "guard_scan"
 SPAN_ENSURE_CACHED = "ensure_cached"
 SPAN_DISPATCH = "dispatch"
+# inside dispatch: the pod batch's host->device upload (one packed
+# buffer, or per leaf)
+SPAN_UPLOAD = "upload"
 SPAN_DEVICE_WAIT = "device_wait"
 # auto-pack's inverse-permutation gather back to the caller's pod order
 SPAN_UNPACK = "unpack"
@@ -109,7 +112,15 @@ HOST_SPANS = frozenset({
     EVENT_RETRY,
 })
 
-ALL_PHASES = KERNEL_PHASES | HOST_SPANS
+# host spans the benchmark's clock join does not place yet: its list
+# (benchmark/hostclock.py SERVICE_SPANS) is pinned equal to HOST_SPANS
+# less the recovery spans and the events, and counts their time under
+# their parent span
+HOST_SUBSPANS = frozenset({
+    SPAN_UPLOAD,
+})
+
+ALL_PHASES = KERNEL_PHASES | HOST_SPANS | HOST_SUBSPANS
 
 # the span skeleton every committed service cycle must carry, in order
 # (tools/trace_smoke.py asserts it cycle-by-cycle)

@@ -63,6 +63,7 @@ from koordinator_tpu.scheduler.plugins.reservation import (
     rebuild_reservations,
     slot_columns,
 )
+from koordinator_tpu.snapshot import hostbatch
 from koordinator_tpu.snapshot.schema import (
     ClusterSnapshot,
     MAX_QUOTA_DEPTH,
@@ -220,7 +221,12 @@ def schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
     rows, padding pass-through rows back in. Both layers are placement-
     preserving (monotone batch-start state; the prefix contracts), so
     cascade=False — the default, and the conformance oracle — produces
-    bit-for-bit identical results (tests/test_cascade.py)."""
+    bit-for-bit identical results (tests/test_cascade.py).
+
+    `pods` may also arrive as its one-buffer upload (a
+    `snapshot.hostbatch.HostBatch`): the program takes it apart on the
+    device first, so a batch costs one host->device transfer."""
+    pods = hostbatch.unpack(pods)
     nodes0, quotas0, gangs0 = snap.nodes, snap.quotas, snap.gangs
     devices0 = snap.devices
     n_nodes = nodes0.num_nodes

@@ -60,6 +60,7 @@ from koordinator_tpu.scheduler.errorhandler import (
 from koordinator_tpu.scheduler.journal import JournalConflict
 from koordinator_tpu.scheduler.metrics_defs import SchedulerMetrics
 from koordinator_tpu.scheduler.plugins.loadaware import LoadAwareConfig
+from koordinator_tpu.snapshot import hostbatch
 from koordinator_tpu.snapshot.schema import ClusterSnapshot, PodBatch
 from koordinator_tpu.snapshot.store import SnapshotStore
 from koordinator_tpu.utils.sync import guarded_by
@@ -72,6 +73,17 @@ def _spanned_devices(snap: ClusterSnapshot) -> int:
     arrays, which run on the default device)."""
     sharding = getattr(snap.nodes.allocatable, "sharding", None)
     return len(sharding.device_set) if sharding is not None else 1
+
+
+def _home_device(snap: ClusterSnapshot):
+    """Where a single-device snapshot's program runs: the device its
+    columns are committed to, else None (host or uncommitted arrays run
+    on the default device). An upload placed there keeps the program's
+    signature the snapshot's own, cycle after cycle."""
+    col = snap.nodes.allocatable
+    if isinstance(col, jax.Array) and col.committed:
+        return next(iter(col.sharding.device_set))
+    return None
 
 
 @guarded_by(
@@ -1015,23 +1027,48 @@ class SchedulerService:
                 log.warning("compile-cache ensure failed; cycle will "
                             "cold-jit", exc_info=True)
 
-    def _run_program(self, snap: ClusterSnapshot, pods: PodBatch,
-                     kwargs: dict):
+    def _inject_fault(self, pods: PodBatch) -> None:
+        """The chaos seam, called at each PROGRAM invocation so chunked
+        cycles inject per sub-batch — a width-dependent OOM stops firing
+        once halving narrows below its threshold, exactly like a real
+        allocator."""
+        if self.fault_injection is not None:
+            self.fault_injection(self._cycle_state, pods)
+
+    def _run_program(self, snap: ClusterSnapshot, pods, kwargs: dict):
         """One guarded/unguarded device-program invocation ->
         `(result, health u32[3] device array or None, node_bad,
         pod_bad)`. With guards on, detection + quarantine + scheduling
-        are ONE fused program (scheduler/guards.py)."""
-        if self.fault_injection is not None:
-            # the chaos seam sits at the PROGRAM invocation, so chunked
-            # cycles inject per sub-batch — a width-dependent OOM stops
-            # firing once halving narrows below its threshold, exactly
-            # like a real allocator
-            self.fault_injection(self._cycle_state, pods)
+        are ONE fused program (scheduler/guards.py). `pods` is a
+        PodBatch or its one-buffer upload (`_upload`), which both
+        programs take apart on the device."""
         if self.guards_enabled:
             return guards.guarded_schedule_batch(snap, pods, self.cfg,
                                                  **kwargs)
         result = core.schedule_batch(snap, pods, self.cfg, **kwargs)
         return result, None, None, None
+
+    def _upload(self, pods: PodBatch, device):
+        """Move a single-program cycle's batch to `device` (None: the
+        default device, for a snapshot held on the host). A batch of
+        host numpy columns crosses as ONE packed buffer
+        (snapshot/hostbatch.py) that the served program unpacks on the
+        chip; a batch with any column already a jax.Array crosses per
+        leaf."""
+        with self._span(obs_phases.SPAN_UPLOAD) as upl:
+            if hostbatch.host_columns(pods):
+                moved = jax.device_put(hostbatch.pack(pods), device)
+                if upl is not None:
+                    upl.update(path="packed", transfers=1,
+                               bytes=int(moved.words.nbytes))
+                return moved
+            if upl is not None:
+                host = [x for x in jax.tree_util.tree_leaves(pods)
+                        if not isinstance(x, jax.Array)]
+                upl.update(path="leaves", transfers=len(host),
+                           bytes=int(sum(np.asarray(x).nbytes
+                                         for x in host)))
+            return jax.device_put(pods, device)
 
     def _journal_commit(self, chunk: int, n_chunks: int,
                         assignment: np.ndarray) -> None:
@@ -1104,6 +1141,7 @@ class SchedulerService:
                 # chunk of the same width is the same program
                 ensured_widths.add(size)
                 self._ensure_cached(snap, batch, kwargs)
+            self._inject_fault(batch)
             res_i, h_i, nb_i, pb_i = self._run_program(snap, batch, kwargs)
             if self.journal is not None:
                 # the journaled readback is the chunk's COMMIT point
@@ -1144,10 +1182,14 @@ class SchedulerService:
         interrupted run did."""
         self._cycle_state = state
         n_real = None
+        # a single-program cycle on one device uploads its batch there
+        # (`_upload`); the mesh rungs and the chunked rung place the
+        # batch per leaf
+        upload, device = False, None
         if state.single_device:
-            dev = jax.devices()[0]
-            snap = jax.device_put(snap, dev)
-            pods = jax.device_put(pods, dev)
+            device = jax.devices()[0]
+            snap = jax.device_put(snap, device)
+            upload = True
             self._last_mesh_size = 1
         elif state.mesh_shrink:
             # rebuild the mesh over the survivors: pad the node axis to
@@ -1168,29 +1210,30 @@ class SchedulerService:
         else:
             # the normal rung runs where the published snapshot lives
             self._last_mesh_size = _spanned_devices(snap)
+            if self._last_mesh_size == 1:
+                upload, device = True, _home_device(snap)
         if state.cascade_off:
             kwargs = dict(kwargs, cascade=False)
-        if self._forced_chunks is not None:
-            # the journaled layout wins over the ladder in BOTH
-            # directions: a 1-chunk epoch replays as the single
-            # program even on a chunked-rung service (running it
-            # chunked would journal conflicting n_chunks records)
-            if self._forced_chunks > 1:
-                out = self._run_chunked(snap, pods, kwargs,
-                                        self._forced_chunks)
-            else:
-                self._ensure_cached(snap, pods, kwargs)
-                out = self._run_program(snap, pods, kwargs)
-        elif state.chunked:
-            out = self._run_chunked(snap, pods, kwargs,
-                                    2 ** state.chunk_splits)
+        # the journaled layout wins over the ladder in BOTH directions:
+        # a 1-chunk epoch replays as the single program even on a
+        # chunked-rung service (running it chunked would journal
+        # conflicting n_chunks records)
+        n_chunks = (self._forced_chunks if self._forced_chunks is not None
+                    else 2 ** state.chunk_splits)
+        if n_chunks > 1:
+            if state.single_device:
+                pods = jax.device_put(pods, device)
+            out = self._run_chunked(snap, pods, kwargs, n_chunks)
         else:
-            # the normal AND mesh-shrink paths ensure here: on the
-            # shrink rung `snap`/`pods` already carry the padded,
-            # resharded survivor-mesh forms, so the cache key is
-            # exactly the program about to dispatch
-            self._ensure_cached(snap, pods, kwargs)
-            out = self._run_program(snap, pods, kwargs)
+            # the single-program paths ensure here: on the shrink rung
+            # `snap`/`pods` already carry the padded, resharded
+            # survivor-mesh forms, and an uploaded batch carries its
+            # layout, so the cache key is exactly the program about to
+            # dispatch
+            batch = self._upload(pods, device) if upload else pods
+            self._ensure_cached(snap, batch, kwargs)
+            self._inject_fault(pods)
+            out = self._run_program(snap, batch, kwargs)
         if n_real is not None:
             from koordinator_tpu.parallel import mesh as meshlib
 

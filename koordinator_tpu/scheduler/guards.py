@@ -54,6 +54,7 @@ import jax.numpy as jnp
 
 from koordinator_tpu.scheduler import core
 from koordinator_tpu.scheduler.plugins import loadaware
+from koordinator_tpu.snapshot import hostbatch
 from koordinator_tpu.snapshot.schema import (
     ClusterSnapshot,
     MAX_QUOTA_DEPTH,
@@ -349,7 +350,10 @@ def guarded_schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
     healthy inputs). Returns `(result, health, node_bad, pod_bad)` with
     `health = [packed word, quarantined nodes, quarantined pods]` as a
     single u32[3] vector — the service's one guard readback; the masks
-    stay on device until the word says there is something to read."""
+    stay on device until the word says there is something to read.
+    `pods` may also arrive as its one-buffer upload (a `HostBatch`),
+    taken apart inside this program before the guards see it."""
+    pods = hostbatch.unpack(pods)
     node_word, node_bad = _node_defects(snap)
     pod_word, pod_bad = _batch_defects(snap, pods)
     g_snap, g_pods = _quarantine(snap, pods, node_bad, pod_bad)

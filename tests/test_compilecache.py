@@ -280,6 +280,38 @@ def test_precompile_second_run_compiles_nothing(cache_dir):
         c2.deactivate()
 
 
+def test_warmed_dir_serves_a_one_device_host_batch(cache_dir):
+    """warm() compiles the program a one-device service dispatches for
+    a batch of host columns, which crosses as one packed buffer: a
+    fresh service over the warmed dir finds its cycle program there."""
+    ws = small_ws()
+    sizes = precompile.full_sizes(ws.sizes)
+
+    def host(name):
+        return jax.tree_util.tree_map(
+            lambda s: np.ones(s.shape, s.dtype),
+            precompile.abstract_struct(name, sizes))
+
+    c1 = CompileCache().activate()
+    try:
+        r1 = precompile.warm(c1, ws)
+        assert r1["programs"] >= 1 and r1["miss"] == r1["programs"]
+    finally:
+        c1.deactivate()
+    jax.clear_caches()
+    c2 = CompileCache()
+    svc = make_service(c2)
+    try:
+        svc.publish(host("ClusterSnapshot"))
+        svc.schedule(host("PodBatch"))
+        m = svc.metrics
+        assert m.compile_cache_misses.value() == 0, \
+            "the served one-device program must come from the warmed dir"
+        assert m.compile_cache_hits.value() == 1
+    finally:
+        c2.deactivate()
+
+
 def test_enumerator_covers_the_shrunk_mesh_ladder():
     if jax.device_count() < 2:
         pytest.skip("needs >= 2 devices (conftest forces 8 on CPU)")

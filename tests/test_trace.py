@@ -230,7 +230,9 @@ def test_phase_table_check():
     with pytest.raises(ValueError):
         phases.check_phase("koord/not_a_phase")
     assert set(phases.CYCLE_SKELETON) <= phases.HOST_SPANS
-    assert phases.ALL_PHASES == phases.KERNEL_PHASES | phases.HOST_SPANS
+    assert phases.ALL_PHASES == (phases.KERNEL_PHASES | phases.HOST_SPANS
+                                 | phases.HOST_SUBSPANS)
+    assert not phases.HOST_SUBSPANS & phases.HOST_SPANS
 
 
 # --- Histogram.percentile vs numpy ------------------------------------------
@@ -337,8 +339,9 @@ def test_enabled_service_cycle_carries_skeleton():
 # no auto-pack reorder at 16 pods: no journal_append, no unpack)
 MIRRORED = (phases.SPAN_CYCLE, phases.SPAN_ADMIT, phases.SPAN_AMP_CHECK,
             phases.SPAN_PREPARE_BATCH, phases.SPAN_DISPATCH,
-            phases.SPAN_DEVICE_WAIT, phases.SPAN_GUARD_SCAN,
-            phases.SPAN_PUBLISH, phases.SPAN_FINALIZE)
+            phases.SPAN_UPLOAD, phases.SPAN_DEVICE_WAIT,
+            phases.SPAN_GUARD_SCAN, phases.SPAN_PUBLISH,
+            phases.SPAN_FINALIZE)
 
 
 def host_plane_events(trace_dir, names):
@@ -386,7 +389,8 @@ def test_spans_are_mirrored_on_the_profiler_host_plane(tmp_path):
     under its own name, once per cycle, nested as the ring nests it."""
     n = 2
     svc = captured_cycles(tmp_path, True, n)
-    plane = host_plane_events(tmp_path, phases.HOST_SPANS)
+    plane = host_plane_events(tmp_path,
+                              phases.HOST_SPANS | phases.HOST_SUBSPANS)
     ring = [r for r in svc.tracer.records() if r.cycle >= 1]
     for name in MIRRORED:
         assert len(plane.get(name, [])) == n, (name, plane.get(name))
@@ -395,6 +399,7 @@ def test_spans_are_mirrored_on_the_profiler_host_plane(tmp_path):
                if r.name in MIRRORED and r.parent is not None}
     assert (phases.SPAN_AMP_CHECK, phases.SPAN_ADMIT) in parents
     assert (phases.SPAN_ADMIT, phases.SPAN_CYCLE) in parents
+    assert (phases.SPAN_UPLOAD, phases.SPAN_DISPATCH) in parents
     for child, parent in parents:
         for (cs, ce), (ps, pe) in zip(plane[child], plane[parent]):
             assert ps <= cs and ce <= pe, (child, parent)
@@ -409,5 +414,6 @@ def test_disabled_service_opens_no_annotation(tmp_path):
     own annotation and no koordtrace span."""
     captured_cycles(tmp_path, None, 1)
     plane = host_plane_events(
-        tmp_path, phases.HOST_SPANS | {phases.PHASE_SCHEDULE_BATCH})
+        tmp_path, phases.HOST_SPANS | phases.HOST_SUBSPANS
+        | {phases.PHASE_SCHEDULE_BATCH})
     assert set(plane) == {phases.PHASE_SCHEDULE_BATCH}
