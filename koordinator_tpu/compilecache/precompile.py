@@ -11,7 +11,8 @@ enumerable ahead of time:
   - the flagship cycle program (core.schedule_batch, or the guarded
     fusion when the service runs guards) per cascade form, on one
     device with the pod batch as the one buffer the service uploads
-    (snapshot/hostbatch.py);
+    (snapshot/hostbatch.py), in the served form that returns only the
+    leaves it computes (scheduler/forwarding.py);
   - the same under every plausible SHRUNK mesh (devices, devices-1,
     ..., 1) with the node axis padded to each mesh exactly as the
     service's mesh-shrink rung pads it — so device loss fails over
@@ -43,7 +44,7 @@ import numpy as np
 
 from koordinator_tpu.compilecache import keys
 from koordinator_tpu.compilecache.cache import CompileCache
-from koordinator_tpu.scheduler import core
+from koordinator_tpu.scheduler import core, forwarding
 from koordinator_tpu.snapshot import hostbatch
 from koordinator_tpu.snapshot.schema import shape_contract
 
@@ -358,7 +359,9 @@ def enumerate_programs(ws: WorkSet,
 
 
 def _build_cycle(fn, snap_sds, pods_sds, cfg_sds, statics):
-    return fn.lower(snap_sds, pods_sds, cfg_sds, **statics).compile()
+    # the form the service dispatches: only the leaves `fn` computes
+    return forwarding.lower(fn, (snap_sds, pods_sds, cfg_sds),
+                            statics).compile()
 
 
 def _build_tail(snap_sds, counts_sds, assign_sds, pods_sds, cfg_sds,

@@ -49,7 +49,7 @@ from koordinator_tpu.obs import phases as obs_phases
 from koordinator_tpu.obs.memwatch import MemWatch
 from koordinator_tpu.obs.slo import SloTracker
 from koordinator_tpu.obs.trace import NOOP_SPAN, Tracer
-from koordinator_tpu.scheduler import core, guards
+from koordinator_tpu.scheduler import core, forwarding, guards
 from koordinator_tpu.scheduler.errorhandler import (
     Backoff,
     FailureClass,
@@ -593,6 +593,7 @@ class ServicesServer:
     _cycle_replayed="_commit_lock",
     _cycle_state="_commit_lock",
     _last_mesh_size="_commit_lock",
+    _last_forwarding="_commit_lock",
     last_committed_version="_commit_lock",
     schedule_kwargs="_commit_lock",
     # post-commit throughput counters get their own cheap lock so
@@ -756,6 +757,7 @@ class SchedulerService:
         # mesh-shrink rung rebuilds its mesh over exactly this list.
         self.device_health: Optional[Callable[[], list]] = None
         self._last_mesh_size = 1
+        self._last_forwarding: Optional[forwarding.Plan] = None
         self._cycle_state = LadderState()
         self.last_health_word = 0
         self.last_quarantined_pods: Optional[np.ndarray] = None
@@ -1041,12 +1043,15 @@ class SchedulerService:
         pod_bad)`. With guards on, detection + quarantine + scheduling
         are ONE fused program (scheduler/guards.py). `pods` is a
         PodBatch or its one-buffer upload (`_upload`), which both
-        programs take apart on the device."""
-        if self.guards_enabled:
-            return guards.guarded_schedule_batch(snap, pods, self.cfg,
-                                                 **kwargs)
-        result = core.schedule_batch(snap, pods, self.cfg, **kwargs)
-        return result, None, None, None
+        programs take apart on the device. It runs the form that
+        returns only the leaves it computes, the rest re-attached on
+        the host (scheduler/forwarding.py); its plan is
+        `_last_forwarding`."""
+        fn = (guards.guarded_schedule_batch if self.guards_enabled
+              else core.schedule_batch)
+        out, self._last_forwarding = forwarding.call(
+            fn, (snap, pods, self.cfg), kwargs)
+        return out if self.guards_enabled else (out, None, None, None)
 
     def _upload(self, pods: PodBatch, device):
         """Move a single-program cycle's batch to `device` (None: the
@@ -1181,6 +1186,7 @@ class SchedulerService:
         ladder state — replay must slice the batch exactly as the
         interrupted run did."""
         self._cycle_state = state
+        self._last_forwarding = None
         n_real = None
         # a single-program cycle on one device uploads its batch there
         # (`_upload`); the mesh rungs and the chunked rung place the
@@ -1295,6 +1301,12 @@ class SchedulerService:
                     if dsp is not None:
                         dsp["ladder"] = state.label()
                         dsp["mesh_size"] = self._last_mesh_size
+                        plan = self._last_forwarding
+                        if plan is not None:
+                            # the served program's own buffers, and the
+                            # leaves re-attached on the host
+                            dsp["outputs"] = plan.outputs
+                            dsp["forwarded"] = plan.forwarded
                 if inv is not None:
                     # back to the CALLER's pod order before anything
                     # (hooks, error chain, debug tables) sees the result

@@ -69,11 +69,14 @@ def _service_program(full_gate: bool):
 
 
 def _compile(snap, pods, cfg, kw, snap_sharding, repl):
-    from koordinator_tpu.scheduler import guards
+    """The served form of the guarded program (scheduler/forwarding.py),
+    the one the service dispatches."""
+    from koordinator_tpu.scheduler import forwarding, guards
 
-    return guards.guarded_schedule_batch.lower(
-        _abstract(snap, snap_sharding), _abstract(pods, repl),
-        _abstract(cfg, repl), **kw).compile()
+    return forwarding.lower(
+        guards.guarded_schedule_batch,
+        (_abstract(snap, snap_sharding), _abstract(pods, repl),
+         _abstract(cfg, repl)), kw).compile()
 
 
 def test_canonical_chunk_compiles(one_chip):
@@ -134,7 +137,7 @@ def test_full_gate_uploaded_batch_compiles(one_chip):
     took ~32 s over the unpack alone."""
     import re
 
-    from koordinator_tpu.scheduler import guards
+    from koordinator_tpu.scheduler import forwarding, guards
     from koordinator_tpu.snapshot import hostbatch
 
     snap, pods, cfg, kw = _service_program(full_gate=True)
@@ -143,7 +146,8 @@ def test_full_gate_uploaded_batch_compiles(one_chip):
     barriers = re.findall(r"%\S+:(\d+) = stablehlo\.optimization_barrier",
                           hlo)
     assert barriers == [str(len(wire.layout))]
-    compiled = guards.guarded_schedule_batch.lower(
-        _abstract(snap, one_chip), wire, _abstract(cfg, one_chip),
-        **kw).compile()
+    compiled = forwarding.lower(
+        guards.guarded_schedule_batch,
+        (_abstract(snap, one_chip), wire, _abstract(cfg, one_chip)),
+        kw).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30

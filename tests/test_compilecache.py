@@ -395,6 +395,44 @@ def test_mesh_shrink_rung_reuses_cached_executable(cache_dir):
         c2.deactivate()
 
 
+def test_chunked_rung_dispatches_the_program_it_warms(cache_dir):
+    """The chunked rung's ensure builds the program its chunks run: a
+    cold cycle writes ONE cycle executable per chunk width to the dir
+    (the dispatch reads back what the ensure compiled), and a service
+    over the warmed dir in a later process compiles zero programs."""
+    snap, pods = service_inputs(9)
+
+    def chunked(cache):
+        svc = make_service(cache)
+        svc.ladder.level = DegradationLadder.L_CHUNKED
+        svc.ladder.chunk_splits = 1  # two chunks of P // 2: one width
+        svc.publish(snap)
+        return svc
+
+    c1 = CompileCache()
+    try:
+        want = np.asarray(chunked(c1).schedule(pods).assignment)
+        assert c1.misses == 1
+    finally:
+        c1.deactivate()
+    cycle = [f for f in os.listdir(cache_dir)
+             if f.startswith("jit_schedule_batch-")]
+    assert len(cycle) == 1, cycle
+
+    jax.clear_caches()
+    c2 = CompileCache()
+    try:
+        svc2 = chunked(c2)
+        with counters.watch() as w:
+            got = np.asarray(svc2.schedule(pods).assignment)
+        assert w.cache_misses == 0, \
+            "the chunked rung must dispatch the executable it warmed"
+        assert c2.misses == 0
+        np.testing.assert_array_equal(got, want)
+    finally:
+        c2.deactivate()
+
+
 # --- where the cache lives (compilecache.persistent_cache_dir) -----------
 
 @pytest.mark.parametrize("from_env", [False, True])
