@@ -12,7 +12,7 @@ mesh axes, jax version, backend). `counters` exposes the JAX
 compilation-cache telemetry the warm-start pins assert on.
 
 Nothing here activates on import. The entry points (chip_smoke.py,
-bench.py, cmd/scheduler.main) call `enable_persistent_cache`, which
+cmd/scheduler.main) call `enable_persistent_cache`, which
 keeps the cache where JAX_COMPILATION_CACHE_DIR says or else in the
 fixed `<repo>/.jax_cache`; the test suite never does (XLA:CPU artifacts
 deserialized on a different machine can segfault — see

@@ -63,7 +63,7 @@ def persistent_cache_dir() -> str:
 def enable_persistent_cache() -> str:
     """Point JAX's persistent compilation cache at
     `persistent_cache_dir()` and return the path. Called by the entry
-    points (chip_smoke.py, bench.py, cmd/scheduler.main) before their
+    points (chip_smoke.py, cmd/scheduler.main) before their
     first compile."""
     import jax
 

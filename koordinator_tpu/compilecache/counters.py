@@ -4,8 +4,8 @@ truth.
 jax.monitoring has no unregister, so ONE pair of process-global
 listeners installs idempotently on first use and feeds module-global
 tallies; `CompileWatcher` snapshots them around a region and exposes
-the deltas. The pin that matters (tests, the warm-cache smoke, bench's
-`cache=` stamp) is `cache_misses == 0`: with a persistent cache dir
+the deltas. The pin that matters (tests, the warm-cache smoke) is
+`cache_misses == 0`: with a persistent cache dir
 active, `/jax/compilation_cache/cache_misses` fires exactly when XLA
 actually compiled, while the backend_compile duration event fires even
 on a persistent-cache HIT (it times compile-OR-retrieve) — so compile

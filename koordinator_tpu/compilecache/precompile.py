@@ -19,7 +19,7 @@ enumerable ahead of time:
     onto an already-compiled program;
   - the canonical tail-compaction form (`tail_program` below: the
     device-resident adaptive tail with buffer donation threaded
-    through, the same donate-(snap, counts) signature the bench jits).
+    through, donating (snap, counts)).
 
 `warm()` lowers + AOT-compiles each through a CompileCache; the JAX
 persistent cache then serves the XLA binary to any later jit dispatch
@@ -49,13 +49,11 @@ from koordinator_tpu.snapshot import hostbatch
 from koordinator_tpu.snapshot.schema import shape_contract
 
 # --- the canonical AOT tail form ------------------------------------------
-# The bench builds its tail closure inline (sweep fused in); the
-# service has no tail yet. This module-level form IS the enumerable
-# tail program: schedule_batch at tail strength threaded through the
-# device-resident compaction loop, with the same (snap, counts) buffer
-# donation the bench's tail jits carry — donated operands alias into
-# the outputs on device backends instead of doubling the snapshot's
-# footprint per pass.
+# The service has no tail yet. This module-level form IS the
+# enumerable tail program: schedule_batch at tail strength threaded
+# through the device-resident compaction loop, with (snap, counts)
+# buffer donation — donated operands alias into the outputs on device
+# backends instead of doubling the snapshot's footprint per pass.
 
 
 @shape_contract(
@@ -82,8 +80,7 @@ def tail_program(snap, counts, assign, pods, cfg, *, tail_chunk: int,
                  min_passes: int, max_passes: int, tail_rounds: int = 4,
                  tail_k: int = 32, cascade: bool = False):
     """The precompilable tail: one jitted program the enumerator can
-    lower for any working-set point (the bench's fused sweep+tail
-    closure is shape-identical in its tail half)."""
+    lower for any working-set point."""
     step = functools.partial(core.schedule_batch, num_rounds=tail_rounds,
                              k_choices=tail_k, score_dims=(0, 1),
                              tie_break=True, quota_depth=2,

@@ -42,7 +42,7 @@ from koordinator_tpu.obs import hloattrib
 
 __all__ = [
     "COST_SIZES", "CostProgram", "enumerate_cost_programs",
-    "program_report", "packing_report", "collect", "flagship_stamp",
+    "program_report", "packing_report", "collect",
 ]
 
 # the proxy working set the checked-in baseline is stamped at: small
@@ -221,17 +221,3 @@ def collect(sizes: Optional[Dict[str, int]] = None,
                    f"packed={report['packed_bytes']} "
                    f"saved={report['saved_bytes']}")
     return entries
-
-
-def flagship_stamp(compiled, num_pods: int) -> Dict[str, float]:
-    """The bench-line cost stamp (bench.py BENCH_COST=1): static cost
-    of the flagship program the bench actually compiled, normalized
-    per pod so lines at different P join the same trajectory."""
-    report = program_report(compiled)
-    return {
-        "flops": report["flops"],
-        "bytes_accessed": report["bytes_accessed"],
-        "hbm_peak_bytes": float(report["peak_bytes"]),
-        "flops_per_pod": (report["flops"] / num_pods
-                          if num_pods else 0.0),
-    }

@@ -4,9 +4,9 @@ registry into one observability dump.
 Three formats, one call:
   * chrome — Chrome trace-event JSON (load the file in Perfetto /
     chrome://tracing),
-  * jsonl — one span record per line (the format profile_fullgate's
-    bisection deltas and trace_fullgate's per-phase table also emit,
-    so all three join on the phase names in obs/phases.py),
+  * jsonl — one span record per line (the format trace_fullgate's
+    per-phase table also emits, so both join on the phase names in
+    obs/phases.py),
   * prom — the metrics `Registry.expose()` text payload.
 
 `dump(...)` writes the chosen formats side by side into a directory;

@@ -6,7 +6,7 @@ Three consumers join on these strings and MUST agree:
     `obs.phase(...)` — koordlint OB001 rejects bare literals),
   * host-side `SchedulerService` cycle spans (obs/trace.py records),
   * the trace parsers (`tools/trace_fullgate.py`,
-    `tools/profile_fullgate.py`, `tools/trace_smoke.py`).
+    `tools/trace_smoke.py`, `benchmark/hostclock.py`).
 
 Names, not enums, because they end up verbatim in Chrome trace-event
 JSON and in the `scheduler_cycle_phase_seconds{phase=...}` label set.

@@ -195,9 +195,8 @@ class Tracer:
                     attrs: Optional[dict] = None,
                     cycle: Optional[int] = None,
                     parent: Optional[str] = None) -> None:
-        """Append a pre-timed span (tools that measure externally —
-        profile_fullgate's gate-bisection deltas — still land in the
-        same buffer/format)."""
+        """Append a pre-timed span (tools that measure externally
+        still land in the same buffer/format)."""
         self._append(SpanRecord(
             cycle=-1 if cycle is None else int(cycle), name=name,
             parent=parent, t_start_ns=int(t_start_ns),
@@ -303,7 +302,7 @@ def jsonl_record(name: str, duration_s: float,
     """A single koordtrace-JSONL line for a synthetic (externally
     timed) span anchored at t=0 — the shared emit path for tools that
     produce per-phase deltas without a live Tracer
-    (tools/profile_fullgate.py, tools/trace_fullgate.py)."""
+    (tools/trace_fullgate.py)."""
     dur_ns = max(0, int(duration_s * 1e9))
     return json.dumps({
         "cycle": cycle, "span": name, "parent": parent,

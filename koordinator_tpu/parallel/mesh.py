@@ -24,7 +24,7 @@ insert collectives.
 Inside `scheduler.core.schedule_batch` (pure jit) annotating the
 operand placements is enough — GSPMD propagates the node sharding
 through every [.., N] intermediate, computes the cascade's stage-1 mask
-shard-locally (zero collectives; tools/mesh_flagship_smoke.py pins that
+shard-locally (zero collectives; tests/test_mesh_flagship.py pins that
 structurally on the compiled HLO) and emits the ICI top-k merge for
 lax.top_k. For stages composed OUTSIDE one jitted program — where GSPMD
 propagation has nothing to propagate through — the explicit shard_map
@@ -76,8 +76,8 @@ def make_mesh(devices: Optional[list] = None, pods_axis: int = 1) -> Mesh:
 
 
 def mesh_axis_sizes(mesh: Mesh) -> dict:
-    """{axis name: size} — the self-describing mesh stamp bench lines
-    carry (a 4-device line must say whether it was 1x4 or 2x2)."""
+    """{axis name: size} — the self-describing mesh stamp (a 4-device
+    mesh must say whether it is 1x4 or 2x2)."""
     return {name: int(size)
             for name, size in zip(mesh.axis_names, mesh.devices.shape)}
 

@@ -4,12 +4,13 @@ Inside one jitted `schedule_batch` GSPMD propagation is enough: the
 snapshot's node sharding flows through every [.., N] intermediate, the
 cascade's stage-1 mask is computed shard-locally with zero collectives,
 and `lax.top_k` over the sharded axis lowers to a per-shard top-k plus
-an ICI merge (tools/mesh_flagship_smoke.py pins both structurally on
-the compiled HLO). Where GSPMD has nothing to propagate through —
-stages composed OUTSIDE one jitted program, such as smoke tools
-building the stage-1 mask standalone, or custom pipelines that want the
-candidate merge before a host-side commit — these shard_map kernels are
-the explicit, conformance-pinned equivalents:
+an ICI merge (tests/test_mesh_flagship.py and
+tests/test_chip_compile.py pin both structurally on the compiled HLO).
+Where GSPMD has nothing to propagate through — stages composed OUTSIDE
+one jitted program, such as tests building the stage-1 mask
+standalone, or custom pipelines that want the candidate merge before a
+host-side commit — these shard_map kernels are the explicit,
+conformance-pinned equivalents:
 
 - `stage1_mask_sharded`: the cascade's stage-1 candidate mask computed
   per node shard (each chip sees only its node columns; the quota

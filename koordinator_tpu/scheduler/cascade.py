@@ -37,7 +37,7 @@ The [P, N] mask follows the snapshot's node-column sharding on a mesh
 (parallel/mesh.candidate_mask_sharding): pods replicate, node columns
 shard, so stage 1 is embarrassingly parallel over chips — the compiled
 stage-1 HLO over sharded inputs contains zero collectives
-(tools/mesh_flagship_smoke.py pins that structurally), and
+(tests/test_mesh_flagship.py pins that structurally), and
 parallel.shardops.stage1_mask_sharded is the explicit shard_map form
 for callers composing the mask outside one jitted program. Pad rows
 appended by parallel.pad_nodes_to_mesh are killed here: schedulable is

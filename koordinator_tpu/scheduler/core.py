@@ -133,8 +133,8 @@ register_struct(ScheduleResult, {
          "nodes.schedulable masks padded node columns; every "
          "[P]-leading result field is -1/0/False for unplaced rows")
 @functools.partial(jax.jit, static_argnames=("num_rounds", "k_choices",
-                                             "score_dims", "approx_topk",
-                                             "tie_break", "enable_numa",
+                                             "score_dims", "tie_break",
+                                             "enable_numa",
                                              "numa_strategy",
                                              "enable_devices",
                                              "device_strategy",
@@ -150,7 +150,6 @@ def schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
                    cfg: loadaware.LoadAwareConfig,
                    num_rounds: int = 4, k_choices: int = 8,
                    score_dims: tuple = None,
-                   approx_topk: bool = False,
                    tie_break: bool = False,
                    enable_numa: bool = True,
                    numa_strategy: str = "most",
@@ -178,10 +177,10 @@ def schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
     [0, topo_prefix). The per-group same-domain [P, P] prefix machinery and
     the (pod x group) gate matmuls then run on [topo_prefix, ...] slices —
     the dominant inner-commit cost on constraint-sparse workloads shrinks
-    quadratically (~16x at the default bench shapes) with bit-identical
+    quadratically (~16x for a 512-row prefix of 2,000 pods) with bit-identical
     results. The caller MUST enforce the contract host-side
-    (synthetic.pack_topo_prefix validates; the bench tail masks overflow
-    pods to a later pass): a member outside the prefix silently drops out
+    (synthetic.pack_topo_prefix validates; SchedulerService's auto-pack
+    derives it per batch): a member outside the prefix silently drops out
     of ALL in-batch topology accounting — the in-step gates and the
     round-level counts alike. None = full width (every row gated; no
     contract).
@@ -739,14 +738,7 @@ def schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
         with obs.phase(obs_phases.PHASE_TOPK):
             masked = jnp.where(feasible, scores, -1.0)
             k = min(k_choices, n_ext)
-            if approx_topk:
-                # TPU-optimized partial reduction (approx_max_k) — the
-                # choice list is a heuristic preference order, so
-                # bounded recall only means an occasional pod falls to
-                # a later round.
-                topk_val, topk_idx = jax.lax.approx_max_k(masked, k)
-            else:
-                topk_val, topk_idx = jax.lax.top_k(masked, k)
+            topk_val, topk_idx = jax.lax.top_k(masked, k)
             topk_idx = topk_idx.astype(jnp.int32)
 
         def inner(inner_carry, _):
@@ -1183,7 +1175,7 @@ def schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
     # outstanding (still to be attempted in a later chunk of the scan or a
     # retry pass). With members outstanding, the placed ones stay ASSUMED —
     # the Permit wait of the reference: pods sit at the barrier until the
-    # gang completes. Without this, a gang spanning bench CHUNK boundaries
+    # gang completes. Without this, a gang spanning batch or chunk boundaries
     # could never form: each chunk would see a partial count and revoke
     # its own members. Reclaim of a waiting gang that never completes is
     # two-tier, as in the reference: `gang_failed` in the result flags
@@ -1311,8 +1303,7 @@ def schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
 def overcommit_arrays_ok(requested, allocatable, num_nodes: int = None,
                          tol: float = 1.0) -> bool:
     """Array form of `overcommit_ok` for callers holding the capacity
-    columns without the snapshot (the bench's non-serialized
-    conformance arrays)."""
+    columns without the snapshot."""
     req = np.asarray(requested)
     alloc = np.asarray(allocatable)
     if num_nodes is not None:
@@ -1338,7 +1329,7 @@ def overcommit_ok(snap: ClusterSnapshot, num_nodes: int = None,
 
 # the (count field, domain field, member field) triples of the
 # cross-batch count rule — THE one place the pairing is encoded;
-# bench.py, the dryrun, and the mesh tests all consume it
+# the service's chunked rung, the dryrun and the tests consume it
 COUNT_FIELDS = ("spread_count0", "anti_count0", "anti_carrier_count0",
                 "aff_count0")
 _COUNT_RULE = (("spread_count0", "spread_domain", "spread_member"),
@@ -1400,7 +1391,7 @@ def charge_domain_counts(count0: jnp.ndarray, dom_matrix: jnp.ndarray,
 # batches and re-schedules them with a heavier program (more rounds /
 # fall-through choices). `tail_pass` is ONE such pass; the host may
 # orchestrate passes itself (one straggler-count readback per adaptive
-# decision — the conformance oracle, bench tail_mode=host), or run
+# decision — the conformance oracle in tests/test_cascade.py), or run
 # `tail_compaction_loop`, which drives the same pass inside a
 # lax.while_loop so the whole adaptive tail — gather, compact, retry,
 # repeat — stays on device and the host reads back ONE stats vector at
@@ -1502,7 +1493,7 @@ def tail_pass(step_fn, snap: ClusterSnapshot, counts: tuple,
     with nothing left is a no-op on the snapshot. `counts` is the
     carried (group x domain) topology-count tuple (COUNT_FIELDS order);
     `charge_counts=False` skips the cross-batch charge for workloads
-    without topology terms (the slim bench path).
+    without topology terms.
     """
     idx, attempt = tail_select(pods, assign, tried, tail_chunk,
                                topo_prefix, topo_mask)
@@ -1561,7 +1552,7 @@ def tail_compaction_loop(step_fn, snap: ClusterSnapshot, counts: tuple,
       remain — a pass that placed nothing must not strand disjoint
       windows that were never tried), up to `max_passes`;
     - only the max_passes cap can leave never_retried > 0 (the caller
-      should surface that loudly — bench does).
+      should surface that loudly).
     """
     p = pods.valid.shape[0]
     min_eff = min(int(min_passes), int(max_passes))

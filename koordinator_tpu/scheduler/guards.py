@@ -314,8 +314,8 @@ def apply_quarantine(snap: ClusterSnapshot, pods: PodBatch,
          "/ valid=False pods; health is [word, bad_nodes, bad_pods] "
          "packed for a single cold-path readback")
 @functools.partial(jax.jit, static_argnames=("num_rounds", "k_choices",
-                                             "score_dims", "approx_topk",
-                                             "tie_break", "enable_numa",
+                                             "score_dims", "tie_break",
+                                             "enable_numa",
                                              "numa_strategy",
                                              "enable_devices",
                                              "device_strategy",
@@ -331,7 +331,6 @@ def guarded_schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
                            cfg: loadaware.LoadAwareConfig,
                            num_rounds: int = 4, k_choices: int = 8,
                            score_dims: tuple = None,
-                           approx_topk: bool = False,
                            tie_break: bool = False,
                            enable_numa: bool = True,
                            numa_strategy: str = "most",
@@ -359,8 +358,8 @@ def guarded_schedule_batch(snap: ClusterSnapshot, pods: PodBatch,
     g_snap, g_pods = _quarantine(snap, pods, node_bad, pod_bad)
     result = core.schedule_batch(
         g_snap, g_pods, cfg, num_rounds=num_rounds, k_choices=k_choices,
-        score_dims=score_dims, approx_topk=approx_topk,
-        tie_break=tie_break, enable_numa=enable_numa,
+        score_dims=score_dims, tie_break=tie_break,
+        enable_numa=enable_numa,
         numa_strategy=numa_strategy, enable_devices=enable_devices,
         device_strategy=device_strategy, quota_depth=quota_depth,
         fit_dims=fit_dims, enable_amplification=enable_amplification,

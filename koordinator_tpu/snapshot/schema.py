@@ -211,8 +211,8 @@ class PodBatch:
 # (chunk slicing, prefix packing, straggler-tail compaction) must
 # permute together; batch-global matrices (selector_match, the
 # (group x domain) tables, count0 surfaces) stay put. THE one list:
-# synthetic.stack_pod_chunks/slice_batch, the bench sweep, and the
-# device-resident tail (scheduler/core.tail_pass) all consume it.
+# synthetic.slice_batch, the prefix packers and the device-resident
+# tail (scheduler/core.tail_pass) all consume it.
 PER_POD_FIELDS = ("requests", "estimated", "qos", "priority_class",
                   "priority", "gang_id", "quota_id", "selector_id",
                   "reservation_owner", "gpu_ratio", "numa_single",

@@ -23,7 +23,7 @@ from koordinator_tpu.snapshot.schema import (
     NUM_AGG,
     NUM_AUX_TYPES,
     NUM_DEV_DIMS,
-    PER_POD_FIELDS as _PER_POD_FIELDS,
+    PER_POD_FIELDS,
     PodBatch,
     QuotaState,
     ReservationState,
@@ -618,8 +618,8 @@ def pack_topo_prefix(pods: PodBatch, chunk: int,
     schedule_batch ranks by (priority desc, index asc), so the reorder
     only permutes tie-breaks among equal-priority pods, exactly like
     any other arrival order of the same queue. The returned mask is in
-    PACKED order (the bench tail uses it to keep retry batches inside
-    the contract)."""
+    PACKED order (core.tail_select takes it to keep retry batches
+    inside the contract)."""
     packed, prefixes, masks = pack_gate_prefixes(pods, chunk,
                                                  align=align)
     return packed, prefixes["topo"], masks["topo"]
@@ -642,8 +642,8 @@ def pack_gate_prefixes(pods: PodBatch, chunk: int,
     topo_constrained_mask), numa = CPU-bind (numa_single), gpu = any
     device request (deviceshare.has_device_request). The numa_prefix
     contract ALSO requires a policy-free snapshot — that part is the
-    caller's to assert (bench does), since the packer never sees
-    nodes."""
+    caller's to assert (SchedulerService's auto-pack does), since the
+    packer never sees nodes."""
     from koordinator_tpu.scheduler.plugins import deviceshare
 
     p = pods.valid.shape[0]
@@ -683,24 +683,6 @@ def pack_gate_prefixes(pods: PodBatch, chunk: int,
                 raise ValueError(
                     f"pack_gate_prefixes: {key} pod escaped its prefix")
     return packed, prefixes, masks
-
-
-def stack_pod_chunks(pods: PodBatch, chunk: int) -> dict:
-    """[P, ...] per-pod columns -> [C, CHUNK, ...] scan operands (the
-    bench sweep shape; zero-copy reshape of the contiguous batch). Shared
-    by bench.py and bench_configs.py so the two harnesses cannot drift."""
-    num = pods.valid.shape[0]
-    if num % chunk:
-        raise ValueError(f"{num} pods not divisible by chunk {chunk}")
-    n_chunks = num // chunk
-    return {f: getattr(pods, f).reshape(n_chunks, chunk,
-                                        *getattr(pods, f).shape[1:])
-            for f in PER_POD_FIELDS}
-
-
-# re-exported from the schema (which owns the per-pod column list) so
-# existing callers keep importing it from here
-PER_POD_FIELDS = _PER_POD_FIELDS
 
 
 def slice_batch(batch: PodBatch, start: int, size: int) -> PodBatch:

@@ -7,7 +7,7 @@ Two conformance oracles, both pinned BIT-identical:
   state), and stage 2's prefix-narrowed heavy gates are pass-through
   beyond the packing prefixes — so placements, scores, and the whole
   post-commit snapshot must match exactly.
-- the host-driven tail orchestration (bench tail_mode=host) is the
+- the host-driven tail orchestration (`_host_tail` below) is the
   oracle for `core.tail_compaction_loop`: the device lax.while_loop
   runs the SAME `core.tail_pass` under the same retry-budget semantics,
   so final placements, pass counts, and straggler stats must match —
@@ -186,8 +186,7 @@ def _overcommitted_tail_setup(seed=2, n_nodes=16):
 
 def _blocking_stats(valid, assign, tried):
     """The oracle's per-pass host readback (the deliberate cost the
-    device loop deletes — in bench tail_mode=host this is the
-    HS006-marked np.asarray)."""
+    device loop deletes; koordlint HS006 flags it in package code)."""
     bad = valid & (np.asarray(assign) < 0)
     return int(bad.sum()), int((bad & ~np.asarray(tried)).sum())
 
@@ -195,7 +194,7 @@ def _blocking_stats(valid, assign, tried):
 def _host_tail(tail_step, snap, counts, assign, pods, cfg, *,
                tail_chunk, min_passes, max_passes, topo_prefix=None,
                topo_mask=None):
-    """The bench tail_mode=host orchestration, verbatim semantics:
+    """The host-driven tail orchestration:
     mandatory passes, then adaptive passes while the count improves or
     never-retried windows remain — one readback per decision."""
     valid = np.asarray(pods.valid)
@@ -231,9 +230,8 @@ def test_device_tail_matches_host_tail():
     snapshots, and [after_sweep, final, never_retried, passes] stats.
     Runs WITH the budgeted constrained (topo_prefix) selection — the
     superset of the plain path; one loop compile instead of two keeps
-    the suite tier-1 fast (the budget-cap/never-retried behavior is
-    pinned end-to-end by test_bench_straggler_overflow_warns, which
-    drives the device loop through bench.py with the cap at 2)."""
+    the suite tier-1 fast. This test alone pins the budget cap: the
+    never-retried count is one of the compared stats."""
     snap, counts, assign, packed, masks, cfg, left0 = \
         _overcommitted_tail_setup()
     tail_step = functools.partial(core.schedule_batch, num_rounds=4,
@@ -244,8 +242,7 @@ def test_device_tail_matches_host_tail():
     topo_kw = dict(topo_prefix=48, topo_mask=jnp.asarray(masks["topo"]))
     # max_passes=3 walks every control edge (mandatory, adaptive
     # continue, budget stop) while keeping the host oracle's eager
-    # passes cheap; the cap-strands-never-retried behavior is pinned
-    # end-to-end by test_bench_straggler_overflow_warns (device mode)
+    # passes cheap
     hs, hc, ha, hstats = _host_tail(
         tail_step, snap, counts, assign, packed, cfg, tail_chunk=64,
         min_passes=2, max_passes=3, **topo_kw)

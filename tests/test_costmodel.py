@@ -138,18 +138,6 @@ def test_donation_visible_in_memory_analysis():
                                 + rep["temp_bytes"])
 
 
-def test_flagship_stamp_normalizes_per_pod():
-    def f(x):
-        return x * 3.0
-
-    compiled = _compile(f, jax.ShapeDtypeStruct((128,), jnp.float32))
-    stamp = costmodel.flagship_stamp(compiled, num_pods=128)
-    rep = costmodel.program_report(compiled)
-    assert stamp["flops"] == rep["flops"]
-    assert stamp["hbm_peak_bytes"] == float(rep["peak_bytes"])
-    assert stamp["flops_per_pod"] == pytest.approx(rep["flops"] / 128)
-
-
 def test_packing_report_prices_the_bf16_representation():
     """The packed snapshot must be strictly smaller than unpacked, with
     saved = unpacked - packed — this is the exact surface the costcheck

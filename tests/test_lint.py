@@ -119,7 +119,8 @@ _TAIL_LOOP_SRC = (
 
 def test_tail_readback_inline_disable(tmp_path, empty_baseline):
     """`# koordlint: disable=HS006` on the finding's line suppresses it
-    in place (the bench host-tail conformance oracle relies on this);
+    in place (a deliberate per-pass readback, as in a conformance
+    oracle, relies on this);
     the analyzer name works as the token too, and the marker only
     covers its OWN line."""
     (tmp_path / "m.py").write_text(_TAIL_LOOP_SRC.format(marker=""))

@@ -1,15 +1,9 @@
 """The promoted sharded flagship path (ISSUE 11): spec-derived
-shardings, node-axis padding, the 2D pods x nodes mesh option, the
-explicit shard_map kernels, and full-gate placement conformance against
-the single-device oracle.
-
-Fast tests run tiny slim-gate programs (cheap compiles); the 4-device
-full-gate conformance run is slow-marked — the same ground gates every
-push as a dedicated tools/ci.sh stage (tools/mesh_flagship_smoke.py at
-2 devices).
+shardings, node-axis padding, the 2D pods x nodes mesh option and the
+explicit shard_map kernels, on tiny slim-gate programs (cheap
+compiles). The served path's sharded conformance, full-gate included,
+is tests/test_service_sharded_*.py.
 """
-
-import importlib
 
 import jax
 import jax.numpy as jnp
@@ -212,6 +206,32 @@ def test_stage1_mask_sharded_conformance():
     )(snap_d, pods, static_ok))
     assert np.array_equal(g, s)
 
+    # full gate over a padded node axis: the same mask, pad columns
+    # dead, and stage 1 (elementwise over node columns) compiles with no
+    # cross-device collective over sharded inputs
+    n = 35
+    snap_p = pad_nodes_to_mesh(
+        synthetic.full_gate_cluster(n, num_quotas=4, num_gangs=2,
+                                    gpus_per_node=4), mesh)
+    snap_d = shard_snapshot(snap_p, mesh)
+    pods = pad_batch_nodes(
+        synthetic.full_gate_pods(256, n, num_quotas=4, num_gangs=2,
+                                 n_anti_groups=4, anti_members=4,
+                                 n_aff_groups=2, aff_members=4),
+        int(snap_p.num_nodes))
+    static_ok, _ = static_gates(snap_d.nodes, pods, cfg)
+    g = np.asarray(stage1_mask(snap_d, pods, static_ok))
+    s = np.asarray(jax.jit(
+        lambda sn, pd, so: shardops.stage1_mask_sharded(mesh, sn, pd, so)
+    )(snap_d, pods, static_ok))
+    assert np.array_equal(g, s)
+    assert g[:, :n].any() and not g[:, n:].any()
+    hlo = jax.jit(stage1_mask).lower(snap_d, pods, static_ok) \
+        .compile().as_text()
+    collectives = ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute", "reduce-scatter")
+    assert [c for c in collectives if c in hlo] == []
+
 
 def test_2d_pods_nodes_mesh_conformance():
     """The 2D mesh option is layout, not semantics: a 2x2 pods x nodes
@@ -237,41 +257,3 @@ def test_2d_pods_nodes_mesh_conformance():
                                    enable_devices=False)
     assert np.array_equal(np.asarray(res2.assignment),
                           np.asarray(res1.assignment))
-
-
-@pytest.mark.slow
-def test_full_gate_sharded_conformance_4dev(monkeypatch):
-    """The ISSUE 11 conformance pin at test scale: the full-gate
-    flagship on a 4-device virtual CPU mesh (node count indivisible by
-    4, so padding rides the hot path) and on one device from the same
-    seed place BIT-IDENTICALLY (exact top-k path), the overcommit
-    invariant holds on real rows, and the multichip line is stamped
-    with its mesh shape. Slow-marked: tools/ci.sh runs the same check
-    at 2 devices as a dedicated stage on every push."""
-    monkeypatch.setenv("BENCH_NODES", "205")
-    monkeypatch.setenv("BENCH_PODS", "2000")
-    monkeypatch.setenv("BENCH_FULL_CHUNK", "500")
-    monkeypatch.setenv("BENCH_MAX_TAIL_PASSES", "4")
-    monkeypatch.setenv("BENCH_EXTRAS", "0")
-    import bench
-    importlib.reload(bench)
-
-    monkeypatch.setenv("BENCH_DEVICES", "4")
-    multi = bench.run_northstar(full_gate=True)
-    monkeypatch.setenv("BENCH_DEVICES", "1")
-    single = bench.run_northstar(full_gate=True)
-
-    assert multi["devices"] == 4 and single["devices"] == 1
-    assert multi["mesh"] == {"nodes": 4}
-    assert "mesh" not in single
-    assert multi["cascade"] is True and multi["tail_mode"] == "device"
-    a_m = multi["arrays"]["assignment"]
-    a_s = single["arrays"]["assignment"]
-    assert (a_m >= 0).sum() > 1000
-    assert np.array_equal(a_m, a_s)
-    n_real = multi["arrays"]["num_nodes"]
-    assert n_real == 205 and a_m.max() < n_real
-    req = multi["arrays"]["requested"]
-    assert req.shape[0] == 208  # padded to the 4-way node axis
-    assert core.overcommit_arrays_ok(req, multi["arrays"]["allocatable"],
-                                     n_real)

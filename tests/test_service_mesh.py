@@ -1,63 +1,60 @@
-"""The north-star bench's multi-device path on the virtual 8-CPU mesh.
-
-`bench.py` shards the node axis over the mesh when >1 device is visible
-(parallel/mesh.py); the driver runs it on real hardware, this test proves
-the sharded program compiles, executes, and places every pod on 8 virtual
-devices (conftest forces the 8-device CPU platform).
+"""The served path on a snapshot node-sharded over the virtual 8-CPU
+mesh (conftest forces 8 devices): closed-loop drains through
+`SchedulerService.schedule()` with each benchmark configuration's
+statics, plus the sharded store's topology deltas and the cross-chunk
+count rule.
 """
-
-import importlib
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+
+import service_mesh
+from koordinator_tpu.scheduler import core
+
+NODES, BATCH, CALLS = 800, 1000, 4
 
 
-def test_bench_runs_sharded_on_8_device_mesh(capsys, monkeypatch):
+@pytest.mark.parametrize("config", ["colocation", "full-gate"])
+def test_service_drains_on_8_device_mesh(config):
+    """4,000 pods in 1,000-pod calls on 800 nodes sharded 100 to a
+    device, counts carried between calls: every committed snapshot
+    keeps the published sharding, no node is overcommitted, and the
+    placements are one device's. Colocation binds every pod but the
+    stragglers its 4-round x 8-choice budget hands back to the queue
+    on one device too (2 strict gangs and 4 batch-tier pods of the
+    first call); the full-gate bulk binds."""
     assert len(jax.devices()) == 8
-    monkeypatch.setenv("BENCH_NODES", "800")
-    monkeypatch.setenv("BENCH_PODS", "4000")
-    monkeypatch.setenv("BENCH_CHUNK", "500")
-    # extras (configs 2-5 + full gate) run at their own scales; the
-    # full-gate sharded path has its own test below
-    monkeypatch.setenv("BENCH_EXTRAS", "0")
-    import bench
-    importlib.reload(bench)
-    bench.main()
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    result = json.loads(out)
-    assert result["devices"] == 8
-    # multi-device lines stamp their mesh shape (1D node mesh here)
-    assert result["mesh"] == {"nodes": 8}
-    assert result["placed"] == 4000
-    assert result["value"] > 0
-    # the slim canonical line is self-describing: device-resident tail,
-    # cascade off (the byte-stable canonical protocol)
-    assert result["tail_mode"] == "device"
-    assert result["cascade"] is False
+    block, snap, batches = service_mesh.make_inputs(config, NODES, BATCH,
+                                                    CALLS)
+    alone = service_mesh.make_service(block)
+    alone.publish(snap)
+    want = service_mesh.drain(alone, batches)
 
+    sharded, batches = service_mesh.shard_over(snap, batches,
+                                               jax.devices())
+    svc = service_mesh.make_service(block)
+    svc.publish(sharded)
+    published = svc.store.current()
 
-def test_bench_full_gate_sharded(capsys, monkeypatch):
-    """The FULL-gate flagship path (NUMA + GPU + taints + spread +
-    anti/affinity all compiled in) on the 8-device mesh, with the
-    topology counts carried across chunks."""
-    monkeypatch.setenv("BENCH_NODES", "800")
-    monkeypatch.setenv("BENCH_PODS", "4000")
-    monkeypatch.setenv("BENCH_FULL_CHUNK", "500")
-    import bench
-    importlib.reload(bench)
-    result = bench.run_northstar(full_gate=True)
-    assert result["devices"] == 8
-    assert result["mesh"] == {"nodes": 8}
-    # tight topology constraints leave stragglers; the bulk must place
-    assert result["placed"] > 3000
-    assert result["metric"].endswith("full_gate")
-    assert result["never_retried"] == 0
-    # full-gate runs through the gate cascade + device tail by default
-    assert result["cascade"] is True
-    assert result["tail_mode"] == "device"
+    def on_commit(i, assignment):
+        committed = svc.store.current()
+        assert service_mesh.moved_leaves(committed, published) == [], i
+        assert svc.metrics.mesh_size.value() == 8
+        assert core.overcommit_arrays_ok(committed.nodes.requested,
+                                         committed.nodes.allocatable,
+                                         NODES), i
+        np.testing.assert_array_equal(assignment, want[i],
+                                      err_msg=f"call {i}")
+
+    assignments = service_mesh.drain(svc, batches, on_commit)
+    placed = sum(int((a >= 0).sum()) for a in assignments)
+    if config == "colocation":
+        assert placed >= 0.99 * BATCH * CALLS
+    else:
+        # tight topology constraints leave stragglers; the bulk binds
+        assert placed > 3000
 
 
 def test_topology_delta_ingests_into_a_sharded_store():

@@ -63,14 +63,6 @@ echo "=== full-gate cascade smoke (2k pods x 200 nodes, CPU) ==="
 # every push even when no test touches it
 JAX_PLATFORMS=cpu python tools/cascade_smoke.py
 
-echo "=== sharded full-gate mesh smoke (2-device virtual CPU mesh) ==="
-# the multichip flagship path on a 2-device virtual mesh: bit-identical
-# placements vs the single-device oracle, pad rows provably dead, the
-# overcommit invariant on real rows, and structural HLO pins (stage-1
-# collective-free, schedule step carries the ICI top-k merge) — never
-# wall-clock (tools/mesh_flagship_smoke.py)
-python tools/mesh_flagship_smoke.py
-
 echo "=== chaos smoke (fault-injection matrix, CPU) ==="
 # every fault class in koordinator_tpu/testing/faults.py: detected
 # (guard word bit / FailureClass / typed delta reason), quarantined,
@@ -110,16 +102,6 @@ echo "=== koordcost mutation smoke (gate liveness + complementarity) ==="
 # cost gate must FAIL on the bytes drift while koordlint and shapecheck
 # — hygiene and shapes, not bytes — must PASS the mutated tree
 JAX_PLATFORMS=cpu python tools/costcheck.py --self-test-mutation
-
-echo "=== benchdiff gate (proxy-shape bench vs checked-in baseline) ==="
-# the comparator's own discrimination proof (seeded noise neutral,
-# planted regressions flagged), then the pinned proxy shape runs fresh
-# and joins against perf/BENCH_BASELINE.json: wall-clock fields loose
-# (live-migrating CI hosts), deterministic counts and BENCH_COST stamps
-# exact — a regression prints BENCH REGRESSION and fails
-python tools/benchdiff.py --self-test
-JAX_PLATFORMS=cpu python tools/benchdiff.py --proxy-run /tmp/_bench_proxy.jsonl
-JAX_PLATFORMS=cpu python tools/benchdiff.py perf/BENCH_BASELINE.json /tmp/_bench_proxy.jsonl
 
 echo "=== warm-cache smoke (compile-cache warm-start gate, CPU) ==="
 # the flagship cycle runs in three REAL child processes against ONE

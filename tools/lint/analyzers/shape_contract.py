@@ -99,7 +99,7 @@ class ShapeContractAnalyzer(Analyzer):
             if rel.startswith("tests/"):
                 continue  # test helpers aren't kernel entry points
             if not isinstance(info.scope_chain[-1], ast.Module):
-                continue  # nested driver closures (bench sweeps)
+                continue  # nested driver closures
             if cindex.contract_for(rel, info.node.name) is not None:
                 continue
             yield Finding(

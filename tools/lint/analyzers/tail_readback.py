@@ -6,8 +6,8 @@ value back EVERY iteration (`np.asarray(stats)`, `.item()`,
 `jax.device_get`, `block_until_ready`). Each blocking transfer pays a
 full device round trip, so a 10-pass tail pays 10 of them — the exact
 pattern the device-resident compaction loop
-(scheduler/core.tail_compaction_loop) deletes from bench.py. This
-analyzer keeps it deleted: a host sync is fine BEFORE or AFTER such a
+(scheduler/core.tail_compaction_loop) replaced. This analyzer keeps
+it out: a host sync is fine BEFORE or AFTER such a
 loop (the single stats readback), never per-iteration inside one.
 
 Heuristic scope (syntactic, per-module): a `while`/`for` statement
@@ -15,9 +15,9 @@ counts as a retry/tail loop when the pattern ``tail|retry|straggl``
 (case-insensitive) matches the enclosing function's name, a name read
 in the loop condition/iterator, or a callee name inside the loop body.
 Loops outside that vocabulary — ordinary data walks that materialize
-arrays — are never flagged; a DELIBERATE per-pass readback (the
-conformance oracle in bench host mode) carries an inline
-``# koordlint: disable=HS006`` marker.
+arrays — are never flagged; a DELIBERATE per-pass readback (a
+conformance oracle) carries an inline ``# koordlint: disable=HS006``
+marker.
 
 Code:
   HS006  blocking host sync inside a retry/tail loop

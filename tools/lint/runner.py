@@ -32,9 +32,9 @@ DEFAULT_BASELINE = os.path.join(
 # name) on the FINDING's own line suppresses it in place. Unlike a
 # baseline entry — which freezes pre-existing debt file-wide and is kept
 # EMPTY in this repo — an inline marker is a visible, reviewed statement
-# at the exact site that the flagged pattern is deliberate (e.g. the
-# host-tail conformance oracle in bench.py that the tail-readback
-# analyzer exists to police everywhere else).
+# at the exact site that the flagged pattern is deliberate (e.g. a
+# conformance oracle that reads back on every pass on purpose, the
+# pattern the tail-readback analyzer polices everywhere else).
 _INLINE_DISABLE_RE = re.compile(r"koordlint:\s*disable=([A-Za-z0-9_,\s-]+)")
 # `# koordlint: disable-file=CODE` on a COMMENT line anywhere in the
 # file suppresses that code (or analyzer) for the whole file — for

@@ -16,8 +16,7 @@ Usage:
       [--guards] [--no-tail] [--cascade on|off|both] [--json]
 
 Exit code 0 on success; the report (per-program hit/warm/miss lines +
-totals) goes to stdout. `bench.py BENCH_PRECOMPILE=1` wraps the same
-warm() for the bench's own working set.
+totals) goes to stdout.
 """
 
 import argparse

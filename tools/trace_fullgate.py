@@ -17,9 +17,8 @@ compiles the SAME program, parses `op_name="...koord/..."` metadata
 out of the HLO text, and joins trace events to phases through the
 instruction-name map. Same program, same names, exact join.
 
-This is the SAMPLED attribution; tools/profile_fullgate.py is the
-SUBTRACTIVE one (gate-off deltas). Both emit koordtrace JSONL keyed by
-the same phase names, so the two can be compared line-for-line.
+It emits koordtrace JSONL keyed by the same phase names as the
+service's spans.
 
 Usage: JAX_PLATFORMS=cpu python tools/trace_fullgate.py [pods] [nodes]
   TRACE_FULLGATE_OUT=<path>  also write the per-phase koordtrace JSONL
